@@ -1,5 +1,5 @@
-# Convenience targets; everything pins JAX_PLATFORMS=cpu (see
-# scripts/verify.sh for why).
+# Convenience targets for the CPU; everything pins JAX_PLATFORMS=cpu (see
+# scripts/verify.sh for why).  On a TPU machine run `python3 chip_smoke.py`.
 
 PY := python
 ENV := JAX_PLATFORMS=cpu PYTHONPATH=src

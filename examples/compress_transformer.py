@@ -17,11 +17,13 @@ from repro.configs import get_config
 from repro.core import ImportanceSpec, compress, neg_loss_perf
 from repro.core.importance import _adam_finetune
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.transformer_host import CostEnv, TransformerHost
 
 
 def main():
+    enable_compile_cache()
     cfg = dataclasses.replace(
         get_config("smollm-135m"), name="smollm-mini", num_layers=6,
         d_model=96, num_heads=4, num_kv_heads=2, head_dim=24, d_ff=256,

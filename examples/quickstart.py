@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.core import (ImportanceSpec, WallClockOracle, accuracy_perf,
                         compress, xent_loss)
 from repro.core.importance import _adam_finetune
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn, cnn_host, zoo
 
 
@@ -36,6 +37,7 @@ def toy_task(key, n, hw, classes=4):
 
 
 def main():
+    enable_compile_cache()
     net = zoo.tiny_resnet(num_classes=4, in_hw=16, width=8, blocks=(2, 2))
     params = cnn.init_params(net, jax.random.PRNGKey(0))
     xtr, ytr = toy_task(jax.random.PRNGKey(1), 256, 16)

@@ -36,6 +36,7 @@ import jax
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh, mesh_info
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.runtime import serving
 from repro.sharding.rules import make_unit_rules
@@ -43,6 +44,7 @@ from repro.train.step import make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)

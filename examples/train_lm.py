@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import GlobalBatcher, SyntheticTokens
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import LoopConfig, train_loop
@@ -36,6 +37,7 @@ def preset_config(name):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=["tiny", "100m"])
     ap.add_argument("--steps", type=int, default=300)

@@ -4,9 +4,11 @@
 #   bash scripts/verify.sh [extra pytest args]
 #   make verify
 #
-# JAX_PLATFORMS=cpu is pinned because on libtpu hosts an unpinned child
-# process stalls for minutes in TPU metadata fetches; every test here is
-# CPU/interpret-mode by design (real-TPU timing has its own benches).
+# JAX_PLATFORMS=cpu is pinned because libtpu is installed and an unpinned
+# JAX goes looking for a TPU.  Every test here runs on the CPU: the ops
+# take their jnp oracles, kernel tests run Pallas in interpret mode, and
+# tests/test_tpu_compile.py compiles for a described v5e without a chip.
+# On a TPU machine, `python3 chip_smoke.py` is the end-to-end check.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

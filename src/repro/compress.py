@@ -78,7 +78,10 @@ def main(argv=None):
     ap.add_argument("--method", default="layermerge",
                     choices=("layermerge", "depth", "layeronly"))
     ap.add_argument("--oracle", default="analytic",
-                    choices=("analytic", "wallclock"))
+                    choices=("analytic", "wallclock"),
+                    help="wallclock times every probe on this device and "
+                         "exits non-zero if any had to fall back to the "
+                         "analytic estimate")
     ap.add_argument("--P", type=int, default=200,
                     help="latency discretization steps (Algorithm 1)")
     ap.add_argument("--quantize", default="none",
@@ -111,7 +114,9 @@ def main(argv=None):
     ap.add_argument("--workers", type=int, default=0,
                     help="fan latency probes out across N subprocess "
                          "workers with lease-based reassignment (requires "
-                         "--cache-dir; tables stay bit-identical)")
+                         "--cache-dir; tables stay bit-identical; refused "
+                         "with --oracle wallclock on an accelerator host, "
+                         "where this process holds the chip)")
     ap.add_argument("--work-dir", default=None,
                     help="shared coordination directory for --workers "
                          "(default: under --cache-dir)")
@@ -119,6 +124,9 @@ def main(argv=None):
 
     from repro.core import ProbeConfig, WallClockOracle, compress
     from repro.core.dist_build import DistBuildError
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     host, source = build_host(args.arch, seed=args.seed, batch=args.batch,
                               seq=args.seq, full=args.full,
@@ -160,6 +168,7 @@ def main(argv=None):
                                if s.quant != "none"),
         "flagged_probes": (len(res.tables.provenance)
                            if res.tables is not None else 0),
+        "quarantined_probes": res.num_quarantined,
         "artifact": args.out,
         "fingerprint": fp[:16],
     }
@@ -170,6 +179,13 @@ def main(argv=None):
                            "dead_workers": rep.dead_workers,
                            "cache_hit": rep.cache_hit}
     print(json.dumps(summary, indent=2))
+    if oracle is not None and res.num_quarantined:
+        # The plan rests on analytic stand-ins for probes that failed on
+        # this device: the artifact is kept for inspection, the run fails.
+        raise SystemExit(
+            f"[repro.compress] {res.num_quarantined} wall-clock probe "
+            f"bucket(s) failed and were quarantined to the analytic "
+            f"estimate")
 
 
 if __name__ == "__main__":

@@ -53,6 +53,9 @@ class CompressResult:
     params: object = None                 # params the plan was built against
     dist_report: object = None            # DistReport when the table build
     #                                       fanned out across workers
+    num_quarantined: int = 0              # probe buckets that fell back to
+    #                                       the analytic estimate (T_orig
+    #                                       pass + table build)
 
     @property
     def speedup(self) -> float:
@@ -146,8 +149,18 @@ def compress(
     visit order, and plans bit-identical to an fp-only run.
     """
     oracle = _resolve_oracle(latency_oracle)
+    if workers > 0:
+        from .dist_build import DistBuildError, check_fanout
+
+        check_fanout(oracle)
+        if cache_dir is None:
+            raise DistBuildError(
+                "workers > 0 requires cache_dir (worker results merge "
+                "through the build journal)")
+    layer_stats = probe_engine.EngineStats(engine=engine)
     layer_lats = probe_engine.layer_latencies(host, oracle, params,
                                               engine=engine,
+                                              stats=layer_stats,
                                               probe_config=probe_config)
     t_orig = sum(layer_lats)
     T0 = budget_ratio * t_orig
@@ -157,17 +170,16 @@ def compress(
         if quantize and quantize != "none":
             raise ValueError("quantize is a merged-segment feature; "
                              "method='layeronly' has no merged units")
-        return _layer_only(host, T0, P, oracle, importance, base_perf, params,
-                           t_orig, layer_lats)
+        res = _layer_only(host, T0, P, oracle, importance, base_perf,
+                          params, t_orig, layer_lats)
+        if res is not None:
+            res.num_quarantined = layer_stats.num_quarantined
+        return res
 
     dist_report = None
     if workers > 0:
-        from .dist_build import DistBuildError, dist_build_tables
+        from .dist_build import dist_build_tables
 
-        if cache_dir is None:
-            raise DistBuildError(
-                "workers > 0 requires cache_dir (worker results merge "
-                "through the build journal)")
         tables, dist_report = dist_build_tables(
             host, cache_dir=cache_dir, workers=workers,
             host_spec=host_spec, method=method, latency_oracle=oracle,
@@ -192,11 +204,14 @@ def compress(
     dp_s = time.perf_counter() - t0
     if res is None:
         return None
+    quarantined = layer_stats.num_quarantined + (
+        tables.stats.num_quarantined if tables.stats is not None else 0)
     return CompressResult(plan=res.plan, tables=tables,
                           original_latency=t_orig,
                           compressed_latency=res.latency,
                           dp_seconds=dp_s, oracle=oracle, host=host,
-                          params=params, dist_report=dist_report)
+                          params=params, dist_report=dist_report,
+                          num_quarantined=quarantined)
 
 
 def _layer_only(host, T0, P, oracle, importance, base_perf, params, t_orig,

@@ -62,10 +62,12 @@ import subprocess
 import sys
 import time
 
+import jax
+
 from repro.testing import faults
 
 from . import probe_engine, table_cache
-from .latency import AnalyticTPUOracle
+from .latency import AnalyticTPUOracle, WallClockOracle
 from .tables import build_tables, enumerate_probes
 
 #: Module spawned as ``python -m`` for subprocess workers (the launch
@@ -75,6 +77,21 @@ WORKER_MODULE = "repro.launch.distributed"
 
 class DistBuildError(RuntimeError):
     """A distributed build could not proceed (bad specs, drift, deadline)."""
+
+
+def check_fanout(oracle) -> None:
+    """Refuse a wall-clock fan-out from a process that holds an accelerator.
+
+    Workers run on the CPU, while a parent on a TPU host holds the chip
+    (one process per chip): their probes would time the CPU, and the
+    tables would be cached under the parent's device.
+    """
+    backend = jax.default_backend()
+    if isinstance(oracle, WallClockOracle) and backend != "cpu":
+        raise DistBuildError(
+            f"wall-clock probes cannot fan out to worker processes on a "
+            f"{backend} host: the chip belongs to this process, and the "
+            f"workers would time the CPU; build with workers=0")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -604,6 +621,7 @@ def dist_build_tables(host, *, cache_dir: str, workers: int = 2,
     deterministic.
     """
     oracle = latency_oracle or AnalyticTPUOracle()
+    check_fanout(oracle)
     key = table_cache.cache_key(host, oracle, method, importance,
                                 prune=prune, base_perf=base_perf,
                                 engine=engine)

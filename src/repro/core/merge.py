@@ -103,6 +103,9 @@ def merge_conv_pair(w1: jax.Array, w2: jax.Array, *, stride1: int = 1,
         padding=((pad_h, pad_h), (pad_w, pad_w)),
         rhs_dilation=(stride1, stride1),
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        # an offline weight fold: exact fp32 on every backend (the TPU's
+        # default would round the operands to bf16)
+        precision=lax.Precision.HIGHEST,
     )                                                 # (cin, cout, mh, mw)
     return jnp.transpose(out, (2, 3, 0, 1)), False
 
@@ -131,7 +134,8 @@ def merge_bias_through(w2: jax.Array, b1: jax.Array, b2: jax.Array | None,
     if dw2:
         contrib = jnp.sum(w2, axis=(0, 1))[0] * b1      # (c,)
     else:
-        contrib = jnp.einsum("hwio,i->o", w2, b1)
+        contrib = jnp.einsum("hwio,i->o", w2, b1,
+                             precision=lax.Precision.HIGHEST)
     return contrib if b2 is None else b2 + contrib
 
 

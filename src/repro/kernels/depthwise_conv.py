@@ -56,7 +56,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .merged_conv import _VMEM_BUDGET, _round8, phase_extents, phase_major
+from .merged_conv import (_VMEM_BUDGET, LANE, SUBLANE, _round8,
+                          align_tile_wo, phase_extents, phase_major,
+                          round_up, vmem_limit)
 from .ref import apply_activation
 
 
@@ -64,18 +66,21 @@ def choose_group_block(groups: int, cin_g: int, cout_g: int,
                        requested: int | None = None) -> int:
     """Groups per grid step (the channel-block width in group units).
 
-    Depthwise-shaped convs (``cin_g == 1``) get a lane-friendly channel
-    tile: ``bgroups·cout_g`` rounded by :func:`repro.kernels.ops.
-    channel_tile` (a multiple of 8, at most one 128-lane width; the
-    group axis is padded *up*, never searched down).  General grouped
-    convs (``cin_g > 1``) take one group per step — each group is its
-    own dense MXU contraction, so blocking more would only serialize
-    python-unrolled dots inside the kernel.
+    Depthwise-shaped convs (``cin_g == 1``) take one 128-lane channel
+    block: each grid step DMAs its own channel slice of the image, and
+    Mosaic only slices the lane axis at whole 128-lane tiles, so the
+    group axis is padded *up* to a multiple of 128 (never searched
+    down).  An explicit ``requested`` block is rounded by
+    :func:`repro.kernels.ops.channel_tile` (interpret-mode sweeps).
+    General grouped convs (``cin_g > 1``) take one group per step — each
+    group is its own dense MXU contraction, so blocking more would only
+    serialize python-unrolled dots inside the kernel.
     """
     if cin_g == 1:
+        if requested is None:
+            return LANE
         from .ops import channel_tile                 # lazy: ops imports us
-        bc = channel_tile(groups * cout_g, requested)
-        return max(1, bc // cout_g)
+        return max(1, channel_tile(groups * cout_g, requested) // cout_g)
     return 1
 
 
@@ -100,18 +105,19 @@ def choose_tiles_grouped(h: int, w: int, cin_g: int, cout_g: int,
     bcin = bgroups * cin_g
     fixed = kh * kw * bgroups * cin_g * cout_g * itemsize   # weight block
     acc_b = bgroups * cout_g * (4 + itemsize)               # per output elem
+    tiles = max(budget_bytes - fixed, budget_bytes / 4)
 
     shi1 = s + kh - 1
     a_w = 2 * shi1 * s * bcin * itemsize + acc_b
-    b_w = fixed + 2 * shi1 * (kw - 1) * bcin * itemsize
-    if a_w * wo + b_w > budget_bytes:
-        tile_wo = int((budget_bytes - b_w) // a_w)
+    b_w = 2 * shi1 * (kw - 1) * bcin * itemsize
+    if a_w * wo + b_w > tiles:
+        tile_wo = int((tiles - b_w) // a_w)
         return 1, _round8(tile_wo, wo)
 
     swi = s * wo + kw - 1
     a_h = 2 * s * swi * bcin * itemsize + wo * acc_b
-    b_h = fixed + 2 * (kh - 1) * swi * bcin * itemsize
-    tile_ho = int((budget_bytes - b_h) // a_h)
+    b_h = 2 * (kh - 1) * swi * bcin * itemsize
+    tile_ho = int((tiles - b_h) // a_h)
     return _round8(tile_ho, ho), wo
 
 
@@ -227,11 +233,11 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
         tile_ho = a_ho if tile_ho is None else tile_ho
         tile_wo = a_wo if tile_wo is None else tile_wo
     tile_ho = max(1, min(tile_ho, ho))
-    tile_wo = max(1, min(tile_wo, wo))
+    tile_wo = align_tile_wo(tile_wo, wo)
     n_th, n_tw = -(-ho // tile_ho), -(-wo // tile_wo)
     ho_p, wo_p = n_th * tile_ho, n_tw * tile_wo
     ph, pw, dh, dw = phase_extents(kh, kw, s)
-    shp, swp = tile_ho + dh, tile_wo + dw
+    shp, swp = tile_ho + dh, round_up(tile_wo + dw, SUBLANE)
 
     # Pad the group axis to a bgroups multiple.  Channels are group-major
     # (lax HWIO grouped layout), so padded input channels and padded
@@ -258,7 +264,7 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
     # Phase-major relayout (shared contract with merged_conv; free at
     # stride 1, one XLA transpose otherwise).
     hs = max(n_th * tile_ho + dh, -(-h // s))
-    ws = max(n_tw * tile_wo + dw, -(-wdt // s))
+    ws = max((n_tw - 1) * tile_wo + swp, -(-wdt // s))
     x = phase_major(x, kh, kw, s, hs, ws)
 
     bcin = bgroups * cin_g
@@ -266,7 +272,7 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
     n_tc = g_p // bgroups
     odt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),     # HBM phase-major image
+        pl.BlockSpec(memory_space=pl.ANY),        # HBM phase-major image
         pl.BlockSpec((kh, kw, bgroups, cin_g * cout_g),
                      lambda bb, th, tw, tc: (0, 0, tc, 0)),
         pl.BlockSpec((1, bc), lambda bb, th, tw, tc: (0, tc)),
@@ -288,6 +294,10 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
         out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, g_p * cout_g), odt),
         scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, bcin), x.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            2 * ph * pw * shp * swp * bcin * x.dtype.itemsize,
+            2 * kh * kw * bgroups * cin_g * cout_g * w.dtype.itemsize,
+            tile_ho * tile_wo * bc * (4 + 2 * odt.itemsize))),
         interpret=interpret,
     )(*operands)
     if (ho_p, wo_p) != (ho, wo) or g_p != groups:

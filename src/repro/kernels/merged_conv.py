@@ -77,6 +77,32 @@ from .ref import apply_activation
 # scratch + weight block + fp32 accumulator + output block, inside
 # ~16 MiB/core with room for Mosaic's own spills.
 _VMEM_BUDGET = 6 * 2 ** 20
+# Upper bound on the scoped VMEM a conv kernel may request (v5e has
+# 128 MiB of VMEM per core; the rest stays with XLA).
+_VMEM_CAP = 100 * 2 ** 20
+# Mosaic's (8, 128) fp32 tiling: the width axis of every DMA window and
+# output block is a multiple of 8 sublanes, the channel axis of every
+# DMA window a multiple of 128 lanes (the ops layer pads channels).
+SUBLANE = 8
+LANE = 128
+
+
+def round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def align_tile_wo(tile_wo: int, wo: int) -> int:
+    """Output-width tile as a multiple of 8 sublanes (the output width is
+    padded up to a whole number of tiles and sliced back off)."""
+    return round_up(max(1, min(tile_wo, wo)), SUBLANE)
+
+
+def vmem_limit(*block_bytes: float) -> int:
+    """Scoped-VMEM request for a kernel whose pipelined blocks and scratch
+    take ``block_bytes``: twice their sum (Mosaic's own temporaries —
+    the tap copies and the fp32 accumulator — are of the same order),
+    at least the 32 MiB default and at most :data:`_VMEM_CAP`."""
+    return int(min(max(2 * sum(block_bytes), 32 * 2 ** 20), _VMEM_CAP))
 
 
 def phase_extents(kh: int, kw: int, stride: int) -> tuple[int, int, int, int]:
@@ -130,26 +156,32 @@ def choose_tiles(h: int, w: int, cin: int, kh: int, kw: int, stride: int,
     output width and grows the row tile; only when a single full-width
     output row overflows (very wide images) does it shrink ``tile_wo``
     with ``tile_ho = 1``.  Prefers multiples of 8 on the tiled axis.
+
+    A weight block larger than the budget (deep merged kernels at wide
+    channels, e.g. 7×7×512 fp32) does not starve the tiles: they keep a
+    quarter of the budget, and the kernel asks Mosaic for the extra
+    VMEM (:func:`vmem_limit`).
     """
     s = max(stride, 1)
     ho = max((h - kh) // s + 1, 1)
     wo = max((w - kw) // s + 1, 1)
     fixed = kh * kw * cin * bcout * itemsize          # weight block
     acc_b = bcout * (4 + itemsize)                    # per output element
+    tiles = max(budget_bytes - fixed, budget_bytes / 4)
 
     # Single full-width output row: does it fit?
     shi1 = s + kh - 1
     a_w = 2 * shi1 * s * cin * itemsize + acc_b
-    b_w = fixed + 2 * shi1 * (kw - 1) * cin * itemsize
-    if a_w * wo + b_w > budget_bytes:
-        tile_wo = int((budget_bytes - b_w) // a_w)
+    b_w = 2 * shi1 * (kw - 1) * cin * itemsize
+    if a_w * wo + b_w > tiles:
+        tile_wo = int((tiles - b_w) // a_w)
         return 1, _round8(tile_wo, wo)
 
     # Full width fits: grow the row tile.
     swi = s * wo + kw - 1
     a_h = 2 * s * swi * cin * itemsize + wo * acc_b
-    b_h = fixed + 2 * (kh - 1) * swi * cin * itemsize
-    tile_ho = int((budget_bytes - b_h) // a_h)
+    b_h = 2 * (kh - 1) * swi * cin * itemsize
+    tile_ho = int((tiles - b_h) // a_h)
     return _round8(tile_ho, ho), wo
 
 
@@ -314,17 +346,19 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
         tile_ho = a_ho if tile_ho is None else tile_ho
         tile_wo = a_wo if tile_wo is None else tile_wo
     tile_ho = max(1, min(tile_ho, ho))
-    tile_wo = max(1, min(tile_wo, wo))
+    tile_wo = align_tile_wo(tile_wo, wo)
     n_th, n_tw = -(-ho // tile_ho), -(-wo // tile_wo)
     ho_p, wo_p = n_th * tile_ho, n_tw * tile_wo
     ph, pw, dh, dw = phase_extents(kh, kw, s)
-    shp, swp = tile_ho + dh, tile_wo + dw     # per-phase halo'd tile extents
+    # per-phase halo'd tile extents; the width is the DMA window's
+    # second-minor axis, so it is padded to whole sublane tiles
+    shp, swp = tile_ho + dh, round_up(tile_wo + dw, SUBLANE)
 
     # Phase-major relayout; per-phase extents padded so every DMA window
     # is full (static copy sizes) — ragged last tiles read zero rows/cols
     # whose outputs are sliced off below.
     hs = max(n_th * tile_ho + dh, -(-h // s))
-    ws = max(n_tw * tile_wo + dw, -(-wdt // s))
+    ws = max((n_tw - 1) * tile_wo + swp, -(-wdt // s))
     x = phase_major(x, kh, kw, s, hs, ws)
 
     bias = (jnp.zeros((1, cout), jnp.float32) if b is None
@@ -332,7 +366,7 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
     odt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),     # HBM phase-major image
+        pl.BlockSpec(memory_space=pl.ANY),        # HBM phase-major image
         pl.BlockSpec((kh, kw, cin, bcout),
                      lambda bb, th, tw, co: (0, 0, 0, co)),
         pl.BlockSpec((1, bcout), lambda bb, th, tw, co: (0, co)),
@@ -355,6 +389,10 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
         out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, cout), odt),
         scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, cin), x.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            2 * ph * pw * shp * swp * cin * x.dtype.itemsize,
+            2 * kh * kw * cin * bcout * w.dtype.itemsize,
+            tile_ho * tile_wo * bcout * (4 + 2 * odt.itemsize))),
         interpret=interpret,
     )(*operands)
     return out[:, :ho, :wo] if (ho_p, wo_p) != (ho, wo) else out

@@ -82,6 +82,15 @@ def _kernel(x_ref, u_ref, v_ref, *rest, bd: int, n_dblocks: int, bk: int,
         o_ref[...] = (acc + xj.astype(jnp.float32)).astype(o_ref.dtype)
 
 
+def _block(n: int, pref: int) -> int:
+    """The widest block ≤ ``pref`` that tiles ``n`` in 128-lane steps
+    (a padded D of 640 takes 128-wide blocks, not 256/512)."""
+    b = min(pref, n)
+    while n % b and b > 128:
+        b -= 128
+    return b
+
+
 def merged_ffn(x, u, v, *, bm: int = 256, bn: int = 256, bk: int = 256,
                bd: int = 512, u_scale=None, v_scale=None, xq=None,
                interpret: bool = False):
@@ -103,7 +112,8 @@ def merged_ffn(x, u, v, *, bm: int = 256, bn: int = 256, bk: int = 256,
     quant = u_scale is not None
     assert quant == (v_scale is not None), "pass both scales or neither"
     assert xq is None or (quant and xq.shape == x.shape)
-    bm, bn, bk, bd = min(bm, m), min(bn, d), min(bk, r), min(bd, d)
+    bm, bn, bk, bd = (_block(m, bm), _block(d, bn), _block(r, bk),
+                      _block(d, bd))
     assert m % bm == 0 and d % bn == 0 and r % bk == 0 and d % bd == 0, (
         "shapes must tile evenly; pad at the ops.py layer")
     grid = (m // bm, d // bn, r // bk)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from . import quant, ref
 from .depthwise_conv import choose_group_block, depthwise_conv
 from .flash_attention import flash_attention
-from .merged_conv import merged_conv
+from .merged_conv import LANE, merged_conv
 from .merged_ffn import merged_ffn
 from .rglru_scan import rglru_scan
 from .rmsnorm import rmsnorm
@@ -145,6 +145,12 @@ def merged_conv_op(x, w, b=None, *, stride: int = 1,
     bc = channel_tile(cout, bcout)
     w_p, pc = _pad_to(w, 3, bc)
     b_p = None if b is None else jnp.pad(b, (0, pc))
+    # Cin rides the lane axis of the kernel's DMA windows, which Mosaic
+    # slices only at whole 128-lane tiles: zero input channels against
+    # zero weight rows leave every output exact.
+    x, pci = _pad_to(x, 3, LANE)
+    if pci:
+        w_p = jnp.pad(w_p, ((0, 0), (0, 0), (0, pci), (0, 0)))
     ws = out_dtype = None
     if w_scale is not None:
         ws = jnp.pad(w_scale.astype(jnp.float32), (0, pc))

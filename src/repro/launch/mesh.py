@@ -14,12 +14,19 @@ pipeline stages over it (train/pipeline.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """A mesh with Auto axes: the executor places activations through
+    ``with_sharding_constraint``, which only Auto axes accept."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
@@ -32,7 +39,7 @@ def make_host_mesh(*, model: int = 1):
     n = len(jax.devices())
     if model < 1 or n % model != 0:
         raise ValueError(f"model={model} does not divide {n} devices")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def mesh_info(mesh) -> dict:

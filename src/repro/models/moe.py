@@ -170,7 +170,7 @@ def moe_ffn_sharded(p, x, cfg, *, capacity_factor: float = 1.25, rules=None):
     """
     import numpy as np
 
-    from repro.sharding.collectives import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rules.mesh

@@ -36,6 +36,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from repro import kernels
 from repro.models import cnn as _cnn
@@ -44,7 +46,7 @@ from repro.models import moe as MOE
 from repro.models import rglru as RG
 from repro.models import transformer as T
 from repro.models import xlstm as XL
-from repro.sharding.rules import (logical_constraint,
+from repro.sharding.rules import (current_rules, logical_constraint,
                                   param_shardings_with_shapes, use_rules)
 
 from . import ir
@@ -82,6 +84,29 @@ def jit_apply(graph: ir.UnitGraph):
 _CNN_ACT = ("batch", None, None, "act_channels")
 
 
+def _conv_kernel(u, x):
+    """One merged conv unit through its kernel entry point.
+
+    XLA cannot partition a Mosaic kernel, so under a mesh the call runs
+    once per data shard inside a ``shard_map``, its weights replicated.
+    """
+    ws = u.params.get("w_scale")
+    aq = u.quant if (ws is not None and u.quant == "w8a8") else "none"
+    op = kernels.depthwise_conv_op if u.depthwise else kernels.merged_conv_op
+
+    def call(x, w, b, ws):
+        return op(x, w, b, stride=u.stride, w_scale=ws, act_quant=aq)
+
+    args = (x, u.params["w"], u.params["b"], ws)
+    rules = current_rules()
+    if rules is None or rules.mesh is None:
+        return call(*args)
+    bspec = P(*rules.spec(("batch",), x.shape[:1]))
+    return shard_map(call, mesh=rules.mesh,
+                     in_specs=(bspec, P(), P(), P()), out_specs=bspec,
+                     check_vma=False)(*args)
+
+
 def _execute_cnn(graph: ir.UnitGraph, x):
     saved: dict[int, jax.Array] = {}
     x = logical_constraint(x, _CNN_ACT)
@@ -89,20 +114,12 @@ def _execute_cnn(graph: ir.UnitGraph, x):
         saved[0] = x
     for u in graph.units:
         if u.kind == "conv":
-            w, b = u.params["w"], u.params["b"]
-            K = w.shape[0]
+            K = u.params["w"].shape[0]
             lo = (K - 1) // 2
             hi = K - 1 - lo
             if K > 1:
                 x = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0)))
-            ws = u.params.get("w_scale")
-            aq = u.quant if (ws is not None and u.quant == "w8a8") else "none"
-            if u.depthwise:
-                x = kernels.depthwise_conv_op(x, w, b, stride=u.stride,
-                                              w_scale=ws, act_quant=aq)
-            else:
-                x = kernels.merged_conv_op(x, w, b, stride=u.stride,
-                                           w_scale=ws, act_quant=aq)
+            x = _conv_kernel(u, x)
             if u.add_from is not None:
                 base = saved[u.add_from]
                 if "proj" in u.params:
@@ -366,6 +383,13 @@ class GraphExecutor:
         with use_rules(self.rules):
             return self._prefill(self.params if params is None else params,
                                  batch)
+
+    def lower(self, batch, params=None):
+        """The jitted full forward, lowered for ``batch`` (ahead-of-time
+        inspection: ``.compile().as_text()`` names the kernels it runs)."""
+        with use_rules(self.rules):
+            return self._prefill.lower(
+                self.params if params is None else params, batch)
 
     def init_cache(self, batch_size: int, seq_len: int):
         cache = init_cache(self.graph, batch_size, seq_len)

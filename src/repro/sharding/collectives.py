@@ -22,26 +22,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-import inspect
-
-try:                                    # jax >= 0.5 top-level export
-    from jax import shard_map as _jax_shard_map
-except ImportError:                     # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-if "check_vma" in inspect.signature(_jax_shard_map).parameters:
-    shard_map = _jax_shard_map
-else:
-    def shard_map(f, **kwargs):
-        """Map the modern ``check_vma`` kwarg onto jax 0.4.x's ``check_rep``."""
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _jax_shard_map(f, **kwargs)
-
-_shard_map = shard_map                  # module-internal alias
 
 from repro.optim.compress import compressed_psum
 
@@ -100,7 +82,7 @@ def flash_decode_attention(q, k, v, valid, *, mesh: Mesh,
         l = lax.psum(l * corr, axis)
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
-    out = _shard_map(
+    out = shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec, axis), P(bspec, axis), P(bspec, axis)),
         out_specs=P(bspec),
@@ -161,7 +143,7 @@ def gpipe_forward(stage_fn, stage_params, x, *, mesh: Mesh,
                                   jnp.zeros_like(outs)), axis)
         return outs.reshape(x_local.shape)
 
-    return _shard_map(
+    return shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
@@ -172,6 +154,6 @@ def gpipe_forward(stage_fn, stage_params, x, *, mesh: Mesh,
 def compressed_allreduce(grads, *, mesh: Mesh, axis: str = "data"):
     """int8 all-reduce of data-parallel gradients (call on replicated-over-
     axis grads; returns the summed result on every shard)."""
-    fn = _shard_map(lambda g: compressed_psum(g, axis), mesh=mesh,
+    fn = shard_map(lambda g: compressed_psum(g, axis), mesh=mesh,
                     in_specs=P(axis), out_specs=P(axis))
     return fn(grads)

@@ -102,3 +102,81 @@ def test_plan_serialization_roundtrip(setup):
     plan2 = CompressionPlan.from_json(res.plan.to_json())
     assert plan2.segments == res.plan.segments
     assert plan2.A == res.plan.A and plan2.C == res.plan.C
+
+
+# ---------------------------------------------------------------------------
+# python -m repro.compress: the wall-clock guards
+# ---------------------------------------------------------------------------
+
+def _cli(tmp_path, *extra):
+    from repro import compress as cli
+    return cli.main(["--arch", "tiny_resnet", "--budget-ratio", "0.7",
+                     "--P", "50", "--max-span", "2",
+                     "--out", str(tmp_path / "a.npz"), *extra])
+
+
+def test_cli_wallclock_fails_when_a_probe_was_quarantined(tmp_path,
+                                                          monkeypatch):
+    from repro.testing import faults
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    # the first bucket's timing fails on every attempt: it falls back to
+    # the analytic estimate, the artifact is still written, the run fails
+    with faults.inject(faults.Fault("probe.time", "raise", nth=1, times=3)):
+        with pytest.raises(SystemExit) as e:
+            _cli(tmp_path, "--oracle", "wallclock", "--probe-retries", "2")
+    assert "quarantined" in str(e.value.code)
+    assert (tmp_path / "a.npz").exists()
+
+
+def test_cli_refuses_wallclock_workers_off_cpu(tmp_path, monkeypatch,
+                                               capsys):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    # a parent that holds a chip: the refusal comes before any probe runs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit) as e:
+        _cli(tmp_path, "--oracle", "wallclock", "--workers", "2",
+             "--cache-dir", str(tmp_path / "tables"))
+    assert e.value.code == 3
+    assert "workers would time the CPU" in capsys.readouterr().out
+    assert not (tmp_path / "a.npz").exists()
+
+
+# ---------------------------------------------------------------------------
+# The compile cache every entry point enables
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_lands_in_the_env_dir(tmp_path):
+    import os
+    import subprocess
+    import sys
+
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((64, 64)))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == str(tmp_path)
+    assert os.listdir(tmp_path), "nothing was cached in the env dir"
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import pathlib
+
+    from repro.launch import cache
+
+    monkeypatch.delenv(cache.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(path) == root / ".jax_cache"

@@ -181,12 +181,15 @@ def test_choose_tiles_grouped_bounds_working_set(h, w, cin_g, cout_g, k, s,
 
 
 def test_choose_group_block():
-    # depthwise: lane-friendly channel tile, multiple of 8, ≤ 128 lanes
-    assert choose_group_block(32, 1, 1) == 32
-    assert choose_group_block(13, 1, 1) == 16
+    # depthwise: one whole 128-lane channel block (Mosaic slices the lane
+    # axis of a DMA window only at whole tiles; the group axis pads up)
+    assert choose_group_block(32, 1, 1) == 128
+    assert choose_group_block(13, 1, 1) == 128
     assert choose_group_block(960, 1, 1) == 128
+    # an explicit block (interpret-mode sweeps) rounds to a multiple of 8
+    assert choose_group_block(32, 1, 1, 13) == 16
     # channel multiplier folds into the lane width
-    assert choose_group_block(32, 1, 4) * 4 <= 128
+    assert choose_group_block(32, 1, 4, 128) * 4 <= 128
     assert choose_group_block(32, 1, 4) >= 1
     # grouped MXU path: one group per step
     assert choose_group_block(4, 6, 6) == 1

@@ -239,6 +239,22 @@ def test_uncacheable_build_is_loud(tmp_path):
                           workers=2)
 
 
+def test_wallclock_fanout_refused_off_cpu(smoke_host, tmp_path,
+                                          monkeypatch):
+    """A parent that holds a chip cannot hand wall-clock probes to CPU
+    workers: the build refuses before it spawns anything."""
+    import jax
+
+    from repro.core import WallClockOracle
+
+    host, params = smoke_host
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(DistBuildError, match="workers would time the CPU"):
+        dist_build_tables(host, params=params, cache_dir=str(tmp_path),
+                          workers=2, latency_oracle=WallClockOracle())
+    assert os.listdir(tmp_path) == []
+
+
 # ---------------------------------------------------------------------------
 # Publish gating: a non-main process writes NOTHING
 # ---------------------------------------------------------------------------

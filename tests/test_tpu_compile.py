@@ -1,0 +1,150 @@
+"""Compile-only checks of the Pallas kernels for a described TPU v5e.
+
+The TPU compiler is installed beside the CPU backend, so a kernel can be
+compiled for a chip that is described and not attached.  This finds what
+interpret mode cannot: Mosaic refuses a DMA window or a block that is not
+aligned to its (8, 128) tiling, and asks for fast memory a kernel may not
+use.  Nothing runs, so these tests say nothing about values or times.
+
+Every shape below is one that Mosaic refused before the kernels padded
+channels to whole lanes and widths to whole sublanes: the ResNet-34 stem
+(Cin 3), a 3×3 conv at Cin 64, a stride-2 3×3 whose DMA window was 29
+rows wide, a merged 7×7 with a 7×7 output at 512 channels, MobileNetV2's
+depthwise conv at 96 channels, and the rank-merged FFN at smollm's
+D = 576.  The last test compiles a whole merged network under a four-chip
+mesh: XLA cannot partition a Mosaic kernel, so the executor must run each
+one per data shard.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and each test worker imports
+every test file.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro import kernels
+from repro.kernels import ops
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a described-chip compile can be written to the persistent cache but
+    # never read back without the chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _hlo(fn, *shapes):
+    with ops.force_backend("pallas"):
+        return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+# (name, NHWC input, HWIO weight, stride, depthwise)
+CONV_CASES = [
+    ("stem_7x7_s2_cin3", (2, 230, 230, 3), (7, 7, 3, 64), 2, False),
+    ("3x3_cin64", (2, 58, 58, 64), (3, 3, 64, 64), 1, False),
+    ("3x3_s2_cin256_window29", (2, 58, 58, 256), (3, 3, 256, 256), 2,
+     False),
+    ("merged_7x7_out7_c512", (2, 13, 13, 512), (7, 7, 512, 512), 1, False),
+    ("depthwise_3x3_s2_c96", (2, 114, 114, 96), (3, 3, 1, 96), 2, True),
+]
+
+
+# fp32 weights, int8 weights, and int8 weights with int8 activations.
+# With int8 activations the conv kernels take up to a minute each to
+# compile at these sizes, so they are compiled with fp32 and int8
+# weights only.
+FFN_MODES = ["none", "int8", "w8a8"]
+CONV_MODES = ["none", "int8"]
+
+
+@pytest.mark.parametrize("mode", CONV_MODES)
+@pytest.mark.parametrize("name,xs,ws,stride,dw", CONV_CASES,
+                         ids=[c[0] for c in CONV_CASES])
+def test_conv_kernel_compiles(one_chip, name, xs, ws, stride, dw, mode):
+    quant = mode != "none"
+    op = kernels.depthwise_conv_op if dw else kernels.merged_conv_op
+
+    def fn(x, w, b, scale):
+        return op(x, w, b, stride=stride, activation="relu",
+                  w_scale=scale if quant else None, act_quant=mode)
+
+    cout = ws[-1]
+    wdt = jnp.int8 if quant else jnp.float32
+    hlo = _hlo(fn, jax.ShapeDtypeStruct(xs, jnp.float32, sharding=one_chip),
+               jax.ShapeDtypeStruct(ws, wdt, sharding=one_chip),
+               jax.ShapeDtypeStruct((cout,), jnp.float32, sharding=one_chip),
+               jax.ShapeDtypeStruct((cout,), jnp.float32, sharding=one_chip))
+    assert hlo.count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("mode", FFN_MODES)
+def test_merged_ffn_compiles_at_d576(one_chip, mode):
+    quant = mode != "none"
+    d, r = 576, 192
+
+    def fn(x, u, v, us, vs):
+        return kernels.merged_ffn_op(x, u, v, u_scale=us if quant else None,
+                                     v_scale=vs if quant else None,
+                                     act_quant=mode)
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    wdt = jnp.int8 if quant else jnp.float32
+    hlo = _hlo(fn, s((4, 96, d)), s((d, r), wdt), s((r, d), wdt), s((r,)),
+               s((d,)))
+    assert hlo.count(KERNEL) == 1
+
+
+def test_sharded_cnn_executor_compiles_on_four_chips(topo):
+    """A merged ResNet under a data-parallel 2x2 mesh: one kernel per conv
+    unit, each run per data shard, with no collective in the forward."""
+    from repro.core import compress
+    from repro.models import cnn, cnn_host, zoo
+    from repro.runtime import executor, ir
+    from repro.sharding.rules import make_unit_rules, use_rules
+
+    net = zoo.tiny_resnet(num_classes=4, in_hw=16, width=8, blocks=(2, 2))
+    host = cnn_host.CNNHost(net, cnn.init_params(net, jax.random.PRNGKey(0)),
+                            batch=8)
+    res = compress(host, budget_ratio=0.7, P=50)
+    graph = res.lower()
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rules = make_unit_rules(mesh)
+    params = ir.graph_params(graph)
+    shapes = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        params, executor.graph_shardings(rules, graph))
+    x = jax.ShapeDtypeStruct((8, 16, 16, 3), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    with use_rules(rules):
+        hlo = _hlo(lambda p, x: executor.execute(graph, x, params=p),
+                   shapes, x)
+    n_conv = sum(u.kind == "conv" for u in graph.units)
+    assert n_conv > 0 and hlo.count(KERNEL) == n_conv
+    assert "all-gather(" not in hlo
