@@ -1,0 +1,7 @@
+"""merged_conv_roofline: the share of its roofline reached by the merged_conv kernels
+(``bench.readers.kernel_roofline``), moving ``images_per_s``."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.kernel_roofline(ctx, "merged_conv")
