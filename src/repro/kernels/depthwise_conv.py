@@ -245,27 +245,31 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
     pad_g = (-groups) % bgroups
     g_p = groups + pad_g
     if pad_g:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, pad_g * cin_g)))
-    # (kh, kw, cin_g, G·cout_g) → (kh, kw, G_p, cin_g·cout_g): the 4-D
-    # group-blocked weight layout the kernel's BlockSpec tiles over.
-    w4 = w.reshape(kh, kw, cin_g, groups, cout_g).transpose(0, 1, 3, 2, 4)
-    if pad_g:
-        w4 = jnp.pad(w4, ((0, 0), (0, 0), (0, pad_g), (0, 0), (0, 0)))
-    w4 = w4.reshape(kh, kw, g_p, cin_g * cout_g)
-    bias = jnp.zeros((groups, cout_g), jnp.float32) if b is None \
-        else b.reshape(groups, cout_g)
-    bias = jnp.pad(bias, ((0, pad_g), (0, 0))).reshape(1, g_p * cout_g)
-    if w_scale is not None:
-        # per-cout scale follows the bias's group-blocked layout
-        scale_b = w_scale.astype(jnp.float32).reshape(groups, cout_g)
-        scale_b = jnp.pad(scale_b,
-                          ((0, pad_g), (0, 0))).reshape(1, g_p * cout_g)
+        with jax.named_scope("lane_pad"):
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, pad_g * cin_g)))
+    with jax.named_scope("weight_prep"):
+        # (kh, kw, cin_g, G·cout_g) → (kh, kw, G_p, cin_g·cout_g): the 4-D
+        # group-blocked weight layout the kernel's BlockSpec tiles over.
+        w4 = w.reshape(kh, kw, cin_g, groups, cout_g).transpose(
+            0, 1, 3, 2, 4)
+        if pad_g:
+            w4 = jnp.pad(w4, ((0, 0), (0, 0), (0, pad_g), (0, 0), (0, 0)))
+        w4 = w4.reshape(kh, kw, g_p, cin_g * cout_g)
+        bias = jnp.zeros((groups, cout_g), jnp.float32) if b is None \
+            else b.reshape(groups, cout_g)
+        bias = jnp.pad(bias, ((0, pad_g), (0, 0))).reshape(1, g_p * cout_g)
+        if w_scale is not None:
+            # per-cout scale follows the bias's group-blocked layout
+            scale_b = w_scale.astype(jnp.float32).reshape(groups, cout_g)
+            scale_b = jnp.pad(scale_b,
+                              ((0, pad_g), (0, 0))).reshape(1, g_p * cout_g)
 
     # Phase-major relayout (shared contract with merged_conv; free at
     # stride 1, one XLA transpose otherwise).
     hs = max(n_th * tile_ho + dh, -(-h // s))
     ws = max((n_tw - 1) * tile_wo + swp, -(-wdt // s))
-    x = phase_major(x, kh, kw, s, hs, ws)
+    with jax.named_scope("relayout"):
+        x = phase_major(x, kh, kw, s, hs, ws)
 
     bcin = bgroups * cin_g
     bc = bgroups * cout_g
@@ -283,23 +287,28 @@ def depthwise_conv(x, w, b=None, *, stride: int = 1, groups: int,
                                      lambda bb, th, tw, tc: (0, tc)))
         operands.append(scale_b)
     grid = (n, n_th, n_tw, n_tc)
-    out = pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, stride=s, n_th=n_th,
-                          n_tw=n_tw, n_tc=n_tc, cin_g=cin_g, cout_g=cout_g,
-                          activation=activation, quant=w_scale is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, tile_ho, tile_wo, bc),
-                               lambda bb, th, tw, tc: (bb, th, tw, tc)),
-        out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, g_p * cout_g), odt),
-        scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, bcin), x.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
-            2 * ph * pw * shp * swp * bcin * x.dtype.itemsize,
-            2 * kh * kw * bgroups * cin_g * cout_g * w.dtype.itemsize,
-            tile_ho * tile_wo * bc * (4 + 2 * odt.itemsize))),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope("kernel"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, kh=kh, kw=kw, stride=s, n_th=n_th,
+                              n_tw=n_tw, n_tc=n_tc, cin_g=cin_g,
+                              cout_g=cout_g, activation=activation,
+                              quant=w_scale is not None),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, tile_ho, tile_wo, bc),
+                                   lambda bb, th, tw, tc: (bb, th, tw, tc)),
+            out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, g_p * cout_g),
+                                           odt),
+            scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, bcin), x.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+                2 * ph * pw * shp * swp * bcin * x.dtype.itemsize,
+                2 * kh * kw * bgroups * cin_g * cout_g * w.dtype.itemsize,
+                tile_ho * tile_wo * bc * (4 + 2 * odt.itemsize))),
+            interpret=interpret,
+            name="depthwise_conv",
+        )(*operands)
     if (ho_p, wo_p) != (ho, wo) or g_p != groups:
-        out = out[:, :ho, :wo, :cout]
+        with jax.named_scope("crop"):
+            out = out[:, :ho, :wo, :cout]
     return out

@@ -359,10 +359,12 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
     # whose outputs are sliced off below.
     hs = max(n_th * tile_ho + dh, -(-h // s))
     ws = max((n_tw - 1) * tile_wo + swp, -(-wdt // s))
-    x = phase_major(x, kh, kw, s, hs, ws)
+    with jax.named_scope("relayout"):
+        x = phase_major(x, kh, kw, s, hs, ws)
 
-    bias = (jnp.zeros((1, cout), jnp.float32) if b is None
-            else b.reshape(1, cout))
+    with jax.named_scope("weight_prep"):
+        bias = (jnp.zeros((1, cout), jnp.float32) if b is None
+                else b.reshape(1, cout))
     odt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
 
     in_specs = [
@@ -375,24 +377,30 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
     if w_scale is not None:
         in_specs.append(pl.BlockSpec((1, bcout),
                                      lambda bb, th, tw, co: (0, co)))
-        operands.append(w_scale.reshape(1, cout).astype(jnp.float32))
+        with jax.named_scope("weight_prep"):
+            operands.append(w_scale.reshape(1, cout).astype(jnp.float32))
 
     grid = (n, n_th, n_tw, cout // bcout)
-    out = pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, stride=s, n_th=n_th,
-                          n_tw=n_tw, activation=activation,
-                          quant=w_scale is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, tile_ho, tile_wo, bcout),
-                               lambda bb, th, tw, co: (bb, th, tw, co)),
-        out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, cout), odt),
-        scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, cin), x.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
-            2 * ph * pw * shp * swp * cin * x.dtype.itemsize,
-            2 * kh * kw * cin * bcout * w.dtype.itemsize,
-            tile_ho * tile_wo * bcout * (4 + 2 * odt.itemsize))),
-        interpret=interpret,
-    )(*operands)
-    return out[:, :ho, :wo] if (ho_p, wo_p) != (ho, wo) else out
+    with jax.named_scope("kernel"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, kh=kh, kw=kw, stride=s, n_th=n_th,
+                              n_tw=n_tw, activation=activation,
+                              quant=w_scale is not None),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, tile_ho, tile_wo, bcout),
+                                   lambda bb, th, tw, co: (bb, th, tw, co)),
+            out_shape=jax.ShapeDtypeStruct((n, ho_p, wo_p, cout), odt),
+            scratch_shapes=[pltpu.VMEM((2, ph, pw, shp, swp, cin), x.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+                2 * ph * pw * shp * swp * cin * x.dtype.itemsize,
+                2 * kh * kw * cin * bcout * w.dtype.itemsize,
+                tile_ho * tile_wo * bcout * (4 + 2 * odt.itemsize))),
+            interpret=interpret,
+            name="merged_conv",
+        )(*operands)
+    if (ho_p, wo_p) != (ho, wo):
+        with jax.named_scope("crop"):
+            out = out[:, :ho, :wo]
+    return out

@@ -135,34 +135,40 @@ def merged_conv_op(x, w, b=None, *, stride: int = 1,
     the kernel applies ONE scale in the fp32 epilogue.
     """
     if not (_use_pallas() or interpret):
-        if w_scale is not None:
-            y = ref.merged_conv_qref(x, w, b, w_scale, stride=stride,
-                                     act_quant=act_quant)
-        else:
-            y = ref.merged_conv_ref(x, w, b, stride=stride)
-        return ref.apply_activation(y, activation)
+        with jax.named_scope("kernel"):
+            if w_scale is not None:
+                y = ref.merged_conv_qref(x, w, b, w_scale, stride=stride,
+                                         act_quant=act_quant)
+            else:
+                y = ref.merged_conv_ref(x, w, b, stride=stride)
+            return ref.apply_activation(y, activation)
     cout = w.shape[-1]
     bc = channel_tile(cout, bcout)
-    w_p, pc = _pad_to(w, 3, bc)
-    b_p = None if b is None else jnp.pad(b, (0, pc))
+    with jax.named_scope("weight_prep"):
+        w_p, pc = _pad_to(w, 3, bc)
+        b_p = None if b is None else jnp.pad(b, (0, pc))
     # Cin rides the lane axis of the kernel's DMA windows, which Mosaic
     # slices only at whole 128-lane tiles: zero input channels against
     # zero weight rows leave every output exact.
-    x, pci = _pad_to(x, 3, LANE)
-    if pci:
-        w_p = jnp.pad(w_p, ((0, 0), (0, 0), (0, pci), (0, 0)))
+    with jax.named_scope("lane_pad"):
+        x, pci = _pad_to(x, 3, LANE)
     ws = out_dtype = None
-    if w_scale is not None:
-        ws = jnp.pad(w_scale.astype(jnp.float32), (0, pc))
-        out_dtype = x.dtype
-        if act_quant == "w8a8":
+    with jax.named_scope("weight_prep"):
+        if pci:
+            w_p = jnp.pad(w_p, ((0, 0), (0, 0), (0, pci), (0, 0)))
+        if w_scale is not None:
+            ws = jnp.pad(w_scale.astype(jnp.float32), (0, pc))
+            out_dtype = x.dtype
+    if act_quant == "w8a8" and ws is not None:
+        with jax.named_scope("epilogue"):
             x, x_scale = quant.quantize_int8(x)
             ws = ws * x_scale
     y = merged_conv(x, w_p, b_p, stride=stride, bcout=bc, tile_ho=tile_ho,
                     tile_wo=tile_wo, activation=activation, w_scale=ws,
                     out_dtype=out_dtype, interpret=interpret)
     if pc:
-        y = y[..., :cout]
+        with jax.named_scope("crop"):
+            y = y[..., :cout]
     return y
 
 
@@ -188,22 +194,27 @@ def depthwise_conv_op(x, w, b=None, *, stride: int = 1,
     if groups is None:
         groups = x.shape[-1] // w.shape[2]
     if not (_use_pallas() or interpret):
-        if w_scale is not None:
-            y = ref.depthwise_conv_qref(x, w, b, w_scale, stride=stride,
-                                        groups=groups, act_quant=act_quant)
-        else:
-            y = ref.depthwise_conv_ref(x, w, b, stride=stride, groups=groups)
-        return ref.apply_activation(y, activation)
+        with jax.named_scope("kernel"):
+            if w_scale is not None:
+                y = ref.depthwise_conv_qref(x, w, b, w_scale, stride=stride,
+                                            groups=groups,
+                                            act_quant=act_quant)
+            else:
+                y = ref.depthwise_conv_ref(x, w, b, stride=stride,
+                                           groups=groups)
+            return ref.apply_activation(y, activation)
     cin_g = w.shape[2]
     cout_g = w.shape[3] // groups
     bg = choose_group_block(groups, cin_g, cout_g, bgroups)
     ws = out_dtype = None
     if w_scale is not None:
-        ws = w_scale.astype(jnp.float32)
+        with jax.named_scope("weight_prep"):
+            ws = w_scale.astype(jnp.float32)
         out_dtype = x.dtype
         if act_quant == "w8a8":
-            x, x_scale = quant.quantize_int8(x)
-            ws = ws * x_scale
+            with jax.named_scope("epilogue"):
+                x, x_scale = quant.quantize_int8(x)
+                ws = ws * x_scale
     return depthwise_conv(x, w, b, stride=stride, groups=groups, bgroups=bg,
                           tile_ho=tile_ho, tile_wo=tile_wo,
                           activation=activation, w_scale=ws,

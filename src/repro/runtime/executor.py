@@ -31,8 +31,25 @@ Entry points:
 The unit loop is a python loop: compressed networks are shallow by
 construction (that is the point of the paper), so trace cost is small
 and every unit keeps its own fused kernel launch.
+
+Names for profiles.  The CNN forward runs unit ``i`` of ``graph.units``
+under ``jax.named_scope(f"unit{i:02d}")`` and each of its ops under one
+role scope: ``pad`` (spatial padding), ``lane_pad`` (input channels to
+128 lanes, groups to a whole block), ``weight_prep`` (weight, bias and
+scale padding or reshaping done per call), ``relayout`` (phase-major),
+``kernel`` (the ``pallas_call``, named ``merged_conv`` or
+``depthwise_conv``; off the TPU, the jnp oracle), ``crop`` (output
+slices) or ``epilogue`` (residual add, projection, concat, group norm,
+activation; a pool, upsample or attention unit whole); the classifier
+runs under ``head``.  The names
+reach the compiled HLO as ``op_name`` metadata and change nothing else.
+:meth:`GraphExecutor.apply` runs under the profiler span
+``executor.apply``, and ``GraphExecutor.traces`` counts its traces
+(compiles) by input shape.
 """
 from __future__ import annotations
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -112,14 +129,32 @@ def _execute_cnn(graph: ir.UnitGraph, x):
     x = logical_constraint(x, _CNN_ACT)
     if graph.meta.get("save_input"):
         saved[0] = x
-    for u in graph.units:
-        if u.kind == "conv":
-            K = u.params["w"].shape[0]
-            lo = (K - 1) // 2
-            hi = K - 1 - lo
-            if K > 1:
+    for i, u in enumerate(graph.units):
+        with jax.named_scope(f"unit{i:02d}"):
+            x = _cnn_unit(u, x, saved)
+        if u.save_at is not None:
+            saved[u.save_at] = x
+    if graph.meta.get("head") == "classifier":
+        with jax.named_scope("head"):
+            head = graph.params["head"]
+            x = x.mean(axis=(1, 2))
+            x = x @ head["w"] + head["b"]
+    return x
+
+
+def _cnn_unit(u, x, saved):
+    """One CNN unit, its ops under the role scopes of the module docstring:
+    ``pad`` here, the kernel wrappers' own roles, then ``epilogue``."""
+    if u.kind == "conv":
+        K = u.params["w"].shape[0]
+        lo = (K - 1) // 2
+        hi = K - 1 - lo
+        if K > 1:
+            with jax.named_scope("pad"):
                 x = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0)))
-            x = _conv_kernel(u, x)
+        x = _conv_kernel(u, x)
+    with jax.named_scope("epilogue"):
+        if u.kind == "conv":
             if u.add_from is not None:
                 base = saved[u.add_from]
                 if "proj" in u.params:
@@ -148,14 +183,7 @@ def _execute_cnn(graph: ir.UnitGraph, x):
             x = _cnn._tiny_self_attention(x, u.params)
         else:
             raise ValueError(f"unit kind {u.kind!r} in cnn graph")
-        x = logical_constraint(x, _CNN_ACT)
-        if u.save_at is not None:
-            saved[u.save_at] = x
-    if graph.meta.get("head") == "classifier":
-        head = graph.params["head"]
-        x = x.mean(axis=(1, 2))
-        x = x @ head["w"] + head["b"]
-    return x
+        return logical_constraint(x, _CNN_ACT)
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +379,12 @@ def cache_shardings(rules, graph: ir.UnitGraph, cache):
     return param_shardings_with_shapes(rules, cache_axes(graph), cache)
 
 
+def _shapes(tree) -> tuple:
+    """The shape of an array input, or the shapes of a pytree's leaves."""
+    shapes = tuple(tuple(a.shape) for a in jax.tree.leaves(tree))
+    return shapes[0] if len(shapes) == 1 else shapes
+
+
 class GraphExecutor:
     """Jitted, mesh-aware prefill/decode over one :class:`UnitGraph`.
 
@@ -372,15 +406,25 @@ class GraphExecutor:
             params = jax.device_put(params, graph_shardings(self.rules,
                                                             graph))
         self.params = params
-        self._prefill = jax.jit(
-            lambda p, batch: execute(graph, batch, params=p))
+        #: traces of ``apply``, by ``("apply", input shape)``: the Python
+        #: body runs only when JAX traces, so a count above 1, or a key
+        #: that appears during serving, is a recompile.  The body closes
+        #: over the counter, not ``self``, so that dropping the executor
+        #: frees its weights at once.
+        traces = self.traces = collections.Counter()
+
+        def prefill(p, batch):
+            traces["apply", _shapes(batch)] += 1
+            return execute(graph, batch, params=p)
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(
             lambda p, cache, batch: decode_step(ir.bind_params(graph, p),
                                                 cache, batch))
 
     def apply(self, batch, params=None):
         """Full forward (CNN image batch / transformer prefill), jitted."""
-        with use_rules(self.rules):
+        with jax.profiler.TraceAnnotation("executor.apply"), \
+                use_rules(self.rules):
             return self._prefill(self.params if params is None else params,
                                  batch)
 

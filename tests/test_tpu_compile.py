@@ -13,12 +13,16 @@ rows wide, a merged 7×7 with a 7×7 output at 512 channels, MobileNetV2's
 depthwise conv at 96 channels, and the rank-merged FFN at smollm's
 D = 576.  The last test compiles a whole merged network under a four-chip
 mesh: XLA cannot partition a Mosaic kernel, so the executor must run each
-one per data shard.
+one per data shard.  The tiny plans of the benchmark's tests are compiled
+for one chip to see that the kernels' names and the executor's unit and
+role scopes reach the compiled text.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each test worker imports
 every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +32,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
 from repro.kernels import ops
+from _tiny_plans import PLANS, tiny_graph
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -148,3 +153,42 @@ def test_sharded_cnn_executor_compiles_on_four_chips(topo):
     n_conv = sum(u.kind == "conv" for u in graph.units)
     assert n_conv > 0 and hlo.count(KERNEL) == n_conv
     assert "all-gather(" not in hlo
+
+
+ROLE_SCOPE = re.compile(r"/unit\d\d/(pad|lane_pad|weight_prep|relayout|"
+                        r"kernel|crop|epilogue)/|/head/")
+
+
+@pytest.mark.parametrize("zoo_name,plan_file", PLANS,
+                         ids=[p for _, p in PLANS])
+def test_cnn_executor_names_reach_the_compiled_forward(one_chip, zoo_name,
+                                                        plan_file):
+    """On the chip's compiler each kernel is named after its kind, and
+    every instruction made from the forward's ops carries its unit and
+    role (or ``head``) in its ``op_name``; only the parameters' own copies
+    and prefetches are left without one."""
+    from repro.runtime import executor, ir
+
+    net, graph = tiny_graph(zoo_name, plan_file)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        ir.graph_params(graph))
+    x = jax.ShapeDtypeStruct((2, net.in_hw, net.in_hw, net.in_ch),
+                             jnp.float32, sharding=one_chip)
+    hlo = _hlo(lambda p, x: executor.execute(graph, x, params=p), shapes, x)
+    entry = hlo[hlo.index("\nENTRY"):]
+    kernels = 0
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not m:
+            continue
+        if KERNEL in line:
+            kernels += 1
+            kind = m.group(1).split(".")[0]
+            assert kind in ("merged_conv", "depthwise_conv"), line[:120]
+            assert re.search(rf"/unit\d\d/kernel/{kind}/pallas_call$",
+                             op.group(1)), op.group(1)
+        elif op and "jit(" in op.group(1):
+            assert ROLE_SCOPE.search(op.group(1)), (m.group(1), op.group(1))
+    assert kernels == sum(u.kind == "conv" for u in graph.units)
