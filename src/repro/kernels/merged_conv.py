@@ -185,6 +185,21 @@ def choose_tiles(h: int, w: int, cin: int, kh: int, kw: int, stride: int,
     return _round8(tile_ho, ho), wo
 
 
+def plan_tiles(h: int, w: int, cin: int, kh: int, kw: int, stride: int,
+               itemsize: int, bcout: int, tile_ho: int | None = None,
+               tile_wo: int | None = None) -> tuple[int, int]:
+    """The ``(tile_ho, tile_wo)`` :func:`merged_conv` runs: the requested
+    tiles, or :func:`choose_tiles`' where none is given, with the row tile
+    at most the output height and the width tile in whole sublanes."""
+    s = max(stride, 1)
+    if tile_ho is None or tile_wo is None:
+        a_ho, a_wo = choose_tiles(h, w, cin, kh, kw, s, itemsize, bcout)
+        tile_ho = a_ho if tile_ho is None else tile_ho
+        tile_wo = a_wo if tile_wo is None else tile_wo
+    ho, wo = (h - kh) // s + 1, (w - kw) // s + 1
+    return max(1, min(tile_ho, ho)), align_tile_wo(tile_wo, wo)
+
+
 def input_traffic_model(h: int, w: int, cin: int, kh: int, kw: int,
                         stride: int, itemsize: int,
                         tile_ho: int | None = None,
@@ -340,13 +355,8 @@ def merged_conv(x, w, b=None, *, stride: int = 1, bcout: int = 128,
     wo = (wdt - kw) // s + 1
     bcout = min(bcout, cout)
     assert cout % bcout == 0, "pad channels at the ops layer"
-    if tile_ho is None or tile_wo is None:
-        a_ho, a_wo = choose_tiles(h, wdt, cin, kh, kw, s, x.dtype.itemsize,
-                                  bcout)
-        tile_ho = a_ho if tile_ho is None else tile_ho
-        tile_wo = a_wo if tile_wo is None else tile_wo
-    tile_ho = max(1, min(tile_ho, ho))
-    tile_wo = align_tile_wo(tile_wo, wo)
+    tile_ho, tile_wo = plan_tiles(h, wdt, cin, kh, kw, s, x.dtype.itemsize,
+                                  bcout, tile_ho, tile_wo)
     n_th, n_tw = -(-ho // tile_ho), -(-wo // tile_wo)
     ho_p, wo_p = n_th * tile_ho, n_tw * tile_wo
     ph, pw, dh, dw = phase_extents(kh, kw, s)

@@ -176,9 +176,10 @@ class CNNHost:
         @jax.jit
         def fn(x, wgt, b):
             xp = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0))) if K > 1 else x
-            # Time the segment exactly as it deploys: through the Pallas
-            # fast path on TPU (strided and depthwise segments included),
-            # oracle off-TPU.
+            # Time the segment exactly as it deploys at this batch:
+            # through the Pallas fast path on TPU (strided and depthwise
+            # segments included), oracle off-TPU.  A folded narrow input
+            # builds its patch by batch (ops.fold_batch_major).
             if dw:
                 return kernels.depthwise_conv_op(xp, wgt, b, stride=stride)
             return kernels.merged_conv_op(xp, wgt, b, stride=stride)

@@ -192,8 +192,10 @@ def test_corrupt_shard_records_repaired(smoke_host, reference, tmp_path):
     """Garbled shard lines are counted, never trusted: the coordinator
     re-executes those items (repair) and the tables stay bit-identical."""
     host, params = smoke_host
-    with faults.inject(faults.Fault("", "corrupt-shard", nth=1, times=2,
-                                    widx=0)):
+    # both workers garble their first records: either may claim every
+    # item before the other starts
+    with faults.inject(*(faults.Fault("", "corrupt-shard", nth=1, times=2,
+                                      widx=w) for w in range(2))):
         tables, rep = _dist(host, params, tmp_path, 2, lease_s=10.0)
     assert rep.corrupt_records >= 1
     assert rep.repaired, "garbled records were not re-executed"

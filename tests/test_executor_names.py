@@ -34,12 +34,16 @@ def _scopes(graph, x):
 
 
 def _expected_roles(u, x_shape) -> set:
-    """Roles a conv unit cannot do without, from its shapes."""
+    """Roles a conv unit cannot do without, from its shapes.  A narrow
+    input that :func:`ops.fold_taps` folds is lane-padded inside its
+    patch build, under ``relayout``."""
     kh, _, cin_g, _ = u.params["w"].shape
+    n, h, w, cin = x_shape
     want = {"weight_prep", "relayout", "kernel"}
     if kh > 1:
         want.add("pad")
-    if not u.depthwise and x_shape[-1] % 128:
+    if not u.depthwise and cin % 128 and not ops.fold_taps(
+            (n, h + kh - 1, w + kh - 1, cin), u.params["w"].shape, u.stride):
         want.add("lane_pad")
     if u.add_from is not None or u.act not in (None, "none"):
         want.add("epilogue")
@@ -72,6 +76,13 @@ def test_every_unit_op_has_one_role(zoo_name, plan_file):
         if u.kind != "conv":
             continue
         assert _expected_roles(u, shapes[i].shape) <= seen[unit], unit
+        n, h, w, cin = shapes[i].shape
+        kh = u.params["w"].shape[0]
+        folded = not u.depthwise and ops.fold_taps(
+            (n, h + kh - 1, w + kh - 1, cin), u.params["w"].shape, u.stride)
+        fold_scope = f"{unit}/relayout/fold_taps/"
+        assert folded == any(f"{st}/".startswith(fold_scope)
+                             for _, st in eqns), unit
         kind = "depthwise_conv" if u.depthwise else "merged_conv"
         assert (unit, ["kernel", kind]) in kernels
     assert len(kernels) == sum(u.kind == "conv" for u in graph.units)

@@ -13,8 +13,14 @@ mode on CPU against ``lax.conv_general_dilated``:
 * the lane-friendly output-channel tile (``bcout`` regression);
 * the input-traffic model backing the halo-bytes-saved bench;
 * the stride-aware segment enumerator (k coordinate == true merged
-  kernel size on strided spans).
+  kernel size on strided spans);
+* narrow inputs folded into one contraction (``ops.fold_taps``), and the
+  units of the benchmark's plans the rule selects.
 """
+import json
+import os
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -46,6 +52,32 @@ def test_strided_merged_conv_matrix(stride, k):
     b = jnp.asarray(rng.standard_normal(6), jnp.float32)
     y = kernels.merged_conv_op(x, w, b, stride=stride, activation="relu",
                            interpret=True)
+    yr = _oracle(x, w, b, stride, "relu")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=2e-5, atol=2e-5)
+
+
+# (Cin, stride, k) that ops.fold_taps leaves on the tap path: the matrix
+# above has Cin 4, which folds, so these pin the multi-tap kernel at
+# strides 1–3 with Cin padded to 128 and 256 lanes
+TAP_CASES = [(64, 1, 3), (64, 2, 3), (64, 2, 7), (64, 3, 7),
+             (130, 1, 5), (130, 2, 3), (130, 2, 5), (130, 3, 7),
+             (250, 1, 3), (250, 2, 5), (250, 3, 3), (250, 3, 5)]
+
+
+@pytest.mark.parametrize("cin,stride,k", TAP_CASES)
+def test_strided_merged_conv_matrix_tap_path(cin, stride, k):
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(cin * 100 + stride * 10 + k)
+    x = jnp.asarray(rng.standard_normal((2, 15, 13, cin)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((k, k, cin, 13)) * 0.05, jnp.float32)
+    b = jnp.asarray(rng.standard_normal(13), jnp.float32)
+    assert not ops.fold_taps(x.shape, w.shape, stride)
+    before = sum(ops.FOLDED.values())
+    y = kernels.merged_conv_op(x, w, b, stride=stride, activation="relu",
+                               tile_ho=4, interpret=True)
+    assert sum(ops.FOLDED.values()) == before
     yr = _oracle(x, w, b, stride, "relu")
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=2e-5, atol=2e-5)
@@ -86,6 +118,37 @@ def test_merged_conv_property(stride, kh, kw, tile_ho, tile_wo, h, w, bf16):
     y = kernels.merged_conv_op(x, wt, b, stride=stride, tile_ho=tile_ho,
                            tile_wo=tile_wo, activation="relu6",
                            interpret=True)
+    yr = _oracle(x, wt, b, stride, "relu6")
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(yr, np.float32), **TOL[dtype])
+
+
+@given(stride=st.integers(1, 3), kh=st.sampled_from([1, 2, 3, 5, 7]),
+       kw=st.sampled_from([1, 2, 3, 5]), tile_ho=st.integers(1, 6),
+       tile_wo=st.integers(1, 6), h=st.integers(8, 20), w=st.integers(8, 20),
+       cin=st.sampled_from([64, 130, 250]), bf16=st.booleans())
+@settings(max_examples=16, deadline=None)
+def test_merged_conv_property_wide_cin(stride, kh, kw, tile_ho, tile_wo, h,
+                                       w, cin, bf16):
+    """The sweep above at Cin 64, 130 and 250, where most shapes stay on
+    the tap path: each conv runs folded exactly when ``ops.fold_taps``
+    says so, and either path matches the oracle."""
+    from repro.kernels import ops
+
+    if h < kh or w < kw:
+        return
+    dtype = jnp.bfloat16 if bf16 else jnp.float32
+    rng = np.random.default_rng(stride * 1009 + kh * 131 + kw * 17 + cin
+                                + tile_ho * 7 + tile_wo * 3 + h * 29 + w)
+    x = jnp.asarray(rng.standard_normal((1, h, w, cin)), dtype)
+    wt = jnp.asarray(rng.standard_normal((kh, kw, cin, 5)) * 0.05, dtype)
+    b = jnp.asarray(rng.standard_normal(5), dtype)
+    fold = ops.fold_taps(x.shape, wt.shape, stride)
+    before = sum(ops.FOLDED.values())
+    y = kernels.merged_conv_op(x, wt, b, stride=stride, tile_ho=tile_ho,
+                               tile_wo=tile_wo, activation="relu6",
+                               interpret=True)
+    assert sum(ops.FOLDED.values()) - before == int(fold)
     yr = _oracle(x, wt, b, stride, "relu6")
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32), **TOL[dtype])
@@ -174,6 +237,135 @@ def test_odd_channel_counts_correct(cout):
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(_oracle(x, w, b, 2, "relu")),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# folded taps: a narrow input contracts its kh·kw·Cin patch in one dot
+# ---------------------------------------------------------------------------
+
+# (kh, stride, Cin, Cout, H, W, tile_ho): the two stems, MobileNetV2's
+# strided 32 → 96 merge and its 24 → 32 downsampling conv, at small sizes;
+# tile_ho 3 leaves a ragged last row tile (8 and 6 output rows)
+FOLD_CASES = [
+    (7, 2, 3, 64, 21, 24, 3),
+    (3, 2, 3, 32, 19, 17, None),
+    (3, 2, 32, 96, 13, 16, 3),
+    (3, 2, 24, 32, 15, 18, None),
+]
+
+
+@pytest.mark.parametrize("batch", [2, 128])
+@pytest.mark.parametrize("mode", ["none", "int8", "w8a8"])
+@pytest.mark.parametrize("kh,stride,cin,cout,h,w,tile_ho", FOLD_CASES,
+                         ids=[f"{c[0]}x{c[0]}_s{c[1]}_cin{c[2]}"
+                              for c in FOLD_CASES])
+def test_folded_taps_match_oracle(kh, stride, cin, cout, h, w, tile_ho,
+                                  mode, batch):
+    """The folded path agrees with the oracle in float32, with int8
+    weights and with int8 weights and activations, and is taken once:
+    at batch 2 with the taps gathered, at batch 128 through the
+    batch-major convolution (float activations) as well."""
+    from repro.kernels import ops, quant
+
+    assert ops.fold_taps((batch, h, w, cin), (kh, kh, cin, cout), stride)
+    rng = np.random.default_rng(kh * 1000 + cin)
+    x = jnp.asarray(rng.standard_normal((batch, h, w, cin)), jnp.float32)
+    wf = jnp.asarray(rng.standard_normal((kh, kh, cin, cout)) * 0.1,
+                     jnp.float32)
+    b = jnp.asarray(rng.standard_normal(cout), jnp.float32)
+    key = (kh, kh, cin, stride)
+    before = ops.FOLDED[key]
+    if mode == "none":
+        y = kernels.merged_conv_op(x, wf, b, stride=stride, tile_ho=tile_ho,
+                                   activation="relu", interpret=True)
+        yr = _oracle(x, wf, b, stride, "relu")
+    else:
+        wq, ws = quant.quantize_int8(wf, axis=3)
+        y = kernels.merged_conv_op(x, wq, b, stride=stride, tile_ho=tile_ho,
+                                   activation="relu", w_scale=ws,
+                                   act_quant=mode, interpret=True)
+        yr = kernels.apply_activation(kernels.merged_conv_qref(
+            x, wq, b, ws, stride=stride, act_quant=mode), "relu")
+    assert ops.FOLDED[key] == before + 1
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               **TOL[jnp.float32])
+
+
+def test_fold_batch_major_from_batch_32_float_only():
+    """Folded convs build their patch batch-major from batch 32 up, for
+    float activations alone; smaller batches sum the taps."""
+    from repro.kernels import ops
+
+    for n in (1, 2, 8, 31):
+        assert not ops.fold_batch_major((n, 230, 230, 3), jnp.float32)
+    for n in (32, 36, 128, 192, 256):
+        assert ops.fold_batch_major((n, 230, 230, 3), jnp.float32)
+        assert ops.fold_batch_major((n, 230, 230, 3), jnp.bfloat16)
+        assert not ops.fold_batch_major((n, 230, 230, 3), jnp.int8)
+
+
+@pytest.mark.parametrize("kh,stride,cin,cout,h,w,tile_ho",
+                         [FOLD_CASES[0], FOLD_CASES[2]],
+                         ids=["7x7_s2_cin3", "3x3_s2_cin32"])
+def test_folded_batch_major_ragged_batch(kh, stride, cin, cout, h, w,
+                                         tile_ho):
+    """Batch-major at a batch of 36, which does not fill whole sublane
+    tiles of the kernel's ``(Wo, N)`` block, matches the oracle."""
+    from repro.kernels import ops
+
+    x_shape = (36, h, w, cin)
+    assert ops.fold_taps(x_shape, (kh, kh, cin, cout), stride)
+    assert ops.fold_batch_major(x_shape, jnp.float32)
+    rng = np.random.default_rng(kh * 1000 + cin + 36)
+    x = jnp.asarray(rng.standard_normal(x_shape), jnp.float32)
+    wf = jnp.asarray(rng.standard_normal((kh, kh, cin, cout)) * 0.1,
+                     jnp.float32)
+    b = jnp.asarray(rng.standard_normal(cout), jnp.float32)
+    y = kernels.merged_conv_op(x, wf, b, stride=stride, tile_ho=tile_ho,
+                               activation="relu", interpret=True)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_oracle(x, wf, b, stride, "relu")),
+                               **TOL[jnp.float32])
+
+
+def test_fold_taps_selects_the_benchmark_stems_and_narrow_strided_units():
+    """Over the benchmark's stored plans the rule folds ResNet-34's stem
+    and MobileNetV2's stem, its strided 32 → 96 merge and its 24 → 32
+    downsampling conv; never a unit with Cin ≥ 128, a 1×1 or a depthwise
+    unit.  Shapes are the executor's: spatially padded by K − 1."""
+    from repro.kernels import ops
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import cnn_reference as R
+
+    want = {"resnet34": {0}, "mobilenetv2": {0, 1, 4}}
+    for name, units in want.items():
+        with open(os.path.join(root, "bench", "configs",
+                               f"{name}.json")) as f:
+            cfg = json.load(f)
+        with open(os.path.join(root, "bench", "configs",
+                               f"{name}.plan.json")) as f:
+            plan = json.load(f)
+        shapes = R.boundary_shapes(cfg)
+        folded = set()
+        for i, seg in enumerate(plan["segments"]):
+            if R.layer(cfg, seg["j"])["kind"] != "conv":
+                continue
+            (h, w, cin), (_, _, cout) = shapes[seg["i"]], shapes[seg["j"]]
+            k, s = R.geometry(cfg, seg)
+            kept = [l for l in seg["kept"]
+                    if R.layer(cfg, l)["kind"] == "conv"]
+            dw = bool(kept) and all(R.layer(cfg, l)["depthwise"]
+                                    for l in kept)
+            fold = ops.fold_taps((128, h + k - 1, w + k - 1, cin),
+                                 (k, k, 1 if dw else cin, cout), s)
+            if fold:
+                folded.add(i)
+            if cin >= 128 or k == 1 or dw:
+                assert not fold, (name, i)
+        assert folded == units, name
 
 
 # ---------------------------------------------------------------------------

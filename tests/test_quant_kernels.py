@@ -112,6 +112,32 @@ def test_merged_conv_quant_matrix(mode, stride):
     assert maxdiff <= budget, (maxdiff, budget)
 
 
+@pytest.mark.parametrize("mode", ["int8", "w8a8", "fp8"])
+@pytest.mark.parametrize("cin,stride", [(64, 2), (130, 1), (250, 3)])
+def test_merged_conv_quant_tap_path(mode, cin, stride):
+    """The matrix above at input widths that ``ops.fold_taps`` leaves on
+    the multi-tap kernel (its Cin 5 folds), strides 1–3."""
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(cin * 10 + stride)
+    k, cout = 3, 13
+    x = jnp.asarray(rng.standard_normal((2, 13, 13, cin)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((k, k, cin, cout)) * .1, jnp.float32)
+    b = jnp.asarray(rng.standard_normal(cout), jnp.float32)
+    wq, ws = quant.quantize_weight(w, mode, axis=3)
+    xp = _pad(x, k)
+    assert not ops.fold_taps(xp.shape, w.shape, stride)
+    aq = mode if mode == "w8a8" else "none"
+    y = kernels.merged_conv_op(xp, wq, b, stride=stride, w_scale=ws,
+                               act_quant=aq, interpret=True)
+    yq = kernels.merged_conv_qref(xp, wq, b, ws, stride=stride, act_quant=aq)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yq), **QTOL)
+    yf = kernels.merged_conv_ref(xp, w, b, stride=stride)
+    budget = _conv_budget(mode, x, w, fan_in=k * k * cin)
+    maxdiff = float(jnp.max(jnp.abs(y - yf)))
+    assert maxdiff <= budget, (maxdiff, budget)
+
+
 @given(stride=st.integers(1, 2), k=st.sampled_from([1, 3, 5]),
        cin=st.integers(2, 9), cout=st.integers(3, 17),
        h=st.integers(8, 14), mode=st.sampled_from(["int8", "w8a8", "fp8"]))

@@ -11,7 +11,10 @@ channels to whole lanes and widths to whole sublanes: the ResNet-34 stem
 (Cin 3), a 3×3 conv at Cin 64, a stride-2 3×3 whose DMA window was 29
 rows wide, a merged 7×7 with a 7×7 output at 512 channels, MobileNetV2's
 depthwise conv at 96 channels, and the rank-merged FFN at smollm's
-D = 576.  The last test compiles a whole merged network under a four-chip
+D = 576.  MobileNetV2's strided 32 → 96 merge is compiled beside them,
+and the stem is checked to run folded (``ops.fold_taps``): its patch
+written once at 256 lanes, the 3-channel image never at 128.  The last
+test compiles a whole merged network under a four-chip
 mesh: XLA cannot partition a Mosaic kernel, so the executor must run each
 one per data shard.  The tiny plans of the benchmark's tests are compiled
 for one chip to see that the kernels' names and the executor's unit and
@@ -75,6 +78,8 @@ CONV_CASES = [
      False),
     ("merged_7x7_out7_c512", (2, 13, 13, 512), (7, 7, 512, 512), 1, False),
     ("depthwise_3x3_s2_c96", (2, 114, 114, 96), (3, 3, 1, 96), 2, True),
+    ("mbv2_unit01_3x3_s2_cin32", (2, 114, 114, 32), (3, 3, 32, 96), 2,
+     False),
 ]
 
 
@@ -104,6 +109,59 @@ def test_conv_kernel_compiles(one_chip, name, xs, ws, stride, dw, mode):
                jax.ShapeDtypeStruct((cout,), jnp.float32, sharding=one_chip),
                jax.ShapeDtypeStruct((cout,), jnp.float32, sharding=one_chip))
     assert hlo.count(KERNEL) == 1
+
+
+_OUT = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) [\w\-]+\(")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]\{([\d,]*)")
+
+
+@pytest.mark.parametrize("batch", [2, 128])
+@pytest.mark.parametrize("mode", CONV_MODES)
+def test_stem_folds_its_taps_into_the_contraction(one_chip, mode, batch):
+    """The 7×7 stride-2 stem at Cin 3 runs folded, with its taps gathered
+    at batch 2 and batch-major at 128: its patch build is named
+    ``fold_taps``, the kernel's image operand carries K = 256 lanes (147
+    real), and no instruction writes the 3-channel image, or a tap of it,
+    out at 128 lanes: neither padded to 128 channels, as the tap path's
+    lane pad did, nor with its 3 channels on the lane axis.  The patch
+    itself is written once: one instruction has an output as large as
+    its 147 real channels."""
+    quant = mode != "none"
+    (n, h, w, _), ws = (batch, 230, 230, 3), (7, 7, 3, 64)
+
+    def fn(x, wt, b, scale):
+        return kernels.merged_conv_op(x, wt, b, stride=2, activation="relu",
+                                      w_scale=scale if quant else None,
+                                      act_quant=mode)
+
+    wdt = jnp.int8 if quant else jnp.float32
+    hlo = _hlo(fn, jax.ShapeDtypeStruct((n, h, w, 3), jnp.float32,
+                                        sharding=one_chip),
+               jax.ShapeDtypeStruct(ws, wdt, sharding=one_chip),
+               jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip),
+               jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip))
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert "/relayout/fold_taps/" in entry
+    call = next(l for l in entry.splitlines() if KERNEL in l)
+    image = re.search(r"operand_layout_constraints=\{\w+\[([\d,]*)\]",
+                      call).group(1)
+    assert int(image.split(",")[-1]) == 256, call[:200]
+    patch_writes = 0
+    for line in entry.splitlines():
+        out = _OUT.match(line)
+        arrays = _ARRAY.findall(out.group(1) if out else "")
+        patch_writes += any(np.prod([int(d) for d in dims.split(",") if d])
+                            >= n * 112 * 112 * 147 for dims, _ in arrays)
+        for dims, layout in arrays:
+            dims = [int(d) for d in dims.split(",") if d]
+            if len(dims) < 4:
+                continue
+            pixels = np.prod(dims[:-1])
+            lanes_c = int(layout.split(",")[0]) == len(dims) - 1
+            assert not (dims[-1] == 128 and pixels >= n * h * w), line[:160]
+            assert not (dims[-1] == 3 and lanes_c
+                        and pixels >= n * 112 * 112), line[:160]
+    assert patch_writes == 1
 
 
 @pytest.mark.parametrize("mode", FFN_MODES)
