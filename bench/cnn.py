@@ -14,7 +14,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from bench import cnn_reference
+from bench import cnn_reference, cost
 
 
 def init_params(cfg, key):
@@ -28,8 +28,25 @@ def make_inputs(cfg, key, pool: int, batch: int):
         jnp.float32)
 
 
-def reference(cfg, params, x, plan, passes=None):
+def reference(cfg, params, x, plan, passes=None, *, traffic=None):
     return cnn_reference.forward(cfg, params, x, plan, passes)
+
+
+def work(cfg, plan):
+    return cost.work(cfg, plan)
+
+
+def check_config(cfg):
+    """The configuration's layers are the program's zoo network, and its
+    stored plan is one the program accepts, over as many layers."""
+    from repro.core.plan import CompressionPlan
+
+    net = _zoo_net(cfg)
+    plan = CompressionPlan.from_json(cfg["plan_text"])
+    if not plan.num_layers == net.L == len(cfg["layers"]):
+        raise ValueError(f"{cfg['name']}: the plan covers {plan.num_layers} "
+                         f"layers, the program's network {net.L}, the "
+                         f"configuration {len(cfg['layers'])}")
 
 
 def _zoo_net(cfg):
@@ -57,7 +74,8 @@ def _zoo_net(cfg):
     return net
 
 
-def build(cfg, params, plan_text: str, workdir: str, clock=None):
+def build(cfg, params, plan_text: str, workdir: str, clock=None, *,
+          traffic=None):
     """Lower the plan, publish and reload the artifact; the executor.
 
     The plan is lowered by ``CNNHost.lower_plan`` traced once under

@@ -10,7 +10,9 @@ module is the one generic runner.  The order of a run:
 3. the measured window: one client calls the entry back to back, each call
    timed from issue to ``block_until_ready``, until ``--seconds`` pass;
 4. with ``--trace 1``, a second, traced window of ``trace_calls`` calls,
-   reduced by :mod:`bench.traces` and read by the per-layer metrics;
+   reduced by :mod:`bench.traces`, its device time put under the
+   program's unit and role names by :mod:`bench.scopes`, and read by the
+   per-layer metrics;
 5. the peak device memory is read, the program's state freed, and a sample
    of the window's answers, drawn from the seed, is compared with the
    plain reference, recomputed from the seed.
@@ -30,6 +32,8 @@ import tempfile
 import time
 
 import numpy as np
+
+from bench import scopes, traces
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -126,6 +130,26 @@ class MetricContext:
     calls: int               # calls in the traced window
     images_per_s: float      # of the untraced window
     chips: int
+    forwards: int = 1        # forwards of the network per call
+    roles: object = None     # bench.scopes.Roles: device time by role, unit
+    host: dict | None = None  # host spans by name, [[start_ns, dur_ns]]
+    alignment: object = None  # bench.scopes.Alignment of the two clocks
+
+    @classmethod
+    def from_trace(cls, rec: dict, window_s: float, kinds: dict,
+                   names: dict, **kw):
+        """The context of a traced window of ``window_s`` s: ``rec`` from
+        ``bench.traces.extract``; ``kinds`` and ``names`` from the compiled
+        forward (``bench.traces.kernel_kinds``, ``bench.scopes.op_names``);
+        ``kw`` the other fields."""
+        host = rec["host"]
+        modules = ([m[1:] for m in rec["devices"][0]["modules"]]
+                   if rec["devices"] else [])
+        return cls(trace=traces.reduce(rec, window_s, kinds),
+                   roles=scopes.role_seconds(rec, names), host=host,
+                   alignment=scopes.align(host[traces.APPLY],
+                                          host[traces.SYNC], modules),
+                   **kw)
 
 
 def _sample(seed: int, n_calls: int, pool: int, want: int) -> list[int]:
@@ -167,7 +191,8 @@ def reference_outputs(fam, cfg, seed, traffic, picks, passes=None):
         x = x.reshape((n,) + x.shape[2:])
         x = jnp.pad(x, ((0, nb * blk - n),) + ((0, 0),) * (x.ndim - 1))
         ys = jax.lax.map(
-            lambda xb: fam.reference(cfg, params, xb, plan, passes),
+            lambda xb: fam.reference(cfg, params, xb, plan, passes,
+                                     traffic=traffic),
             x.reshape((nb, blk) + x.shape[1:]))
         return ys.reshape((nb * blk,) + ys.shape[2:])[:n]
     return np.asarray(jax.jit(answers)(seed_key(seed), slots))
@@ -183,6 +208,8 @@ class Cell:
         self.cfg = reg.config(self.cell["config"])
         self.traffic = reg.traffic(self.cell["traffic"])
         self.fam = importlib.import_module(f"bench.{self.cfg['family']}")
+        self.forwards = (self.fam.forwards(self.cfg, self.traffic)
+                         if hasattr(self.fam, "forwards") else 1)
         self.precision = jax.default_matmul_precision(
             self.cfg["matmul_precision"])
         kw, kx = jax.random.split(seed_key(seed))
@@ -200,7 +227,8 @@ class Cell:
             work = tempfile.mkdtemp(prefix="bench_")
             try:
                 self.ex = self.fam.build(self.cfg, params,
-                                         self.cfg["plan_text"], work, clock)
+                                         self.cfg["plan_text"], work, clock,
+                                         traffic=t)
             finally:
                 shutil.rmtree(work, ignore_errors=True)
             del params
@@ -307,7 +335,6 @@ def run(reg: Registry, name: str, seed: int, seconds: float, trace: bool,
         t_start: float, device: dict) -> dict:
     """One run of one cell; the result object the contract prints."""
     import jax
-    from bench import traces
 
     compiles = Compiles()
     cell = Cell(reg, name, seed)
@@ -330,14 +357,22 @@ def run(reg: Registry, name: str, seed: int, seconds: float, trace: bool,
         tdir = tempfile.mkdtemp(prefix="bench_trace_")
         try:
             hlo, path, tw = cell.traced_window(t["trace_calls"], tdir)
-            red = traces.reduce(traces.extract(path), tw,
-                                traces.kernel_kinds(hlo))
+            rec = traces.extract(path)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
-        ctx = MetricContext(trace=red, work=cell.cfg["work"], peak=peak,
-                            batch=t["batch"], calls=t["trace_calls"],
-                            images_per_s=images_per_s,
-                            chips=cell.cell["chips"])
+        ctx = MetricContext.from_trace(
+            rec, tw, traces.kernel_kinds(hlo), scopes.op_names(hlo),
+            work=cell.cfg["work"], peak=peak,
+            batch=t["batch"], calls=t["trace_calls"],
+            images_per_s=images_per_s, chips=cell.cell["chips"],
+            forwards=cell.forwards)
+        red = ctx.trace
+        for line in scopes.role_table(ctx.roles, t["trace_calls"]):
+            log(f"{name}: role {line}")
+        al = ctx.alignment
+        log(f"{name}: host - device clock offset "
+            + ("unknown" if al is None else f"{al.offset_ns * 1e-6:.4f} ms "
+               f"(interval {al.width_ns * 1e-6:.4f} ms wide)"))
         for m in reg.metrics("per_layer", name):
             v = reg.reader(m["name"])(ctx)
             if v is not None:
@@ -346,7 +381,8 @@ def run(reg: Registry, name: str, seed: int, seconds: float, trace: bool,
         breakdown = {"device_ops": red.top_ops, "idle_gaps": red.idle_gaps}
     else:
         e2e = {"images_per_s": images_per_s, "setup_s": setup_s,
-               "latency_ms_p95": float(np.percentile(lat, 95)) * 1e3}
+               "latency_ms_p95": float(np.percentile(lat, 95)) * 1e3,
+               "latency_ms_mean": window_s * 1e3 / len(lat)}
         for m in reg.metrics("end_to_end", name):
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")

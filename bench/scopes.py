@@ -1,15 +1,5 @@
-#!/usr/bin/env python3
 """The program's own names in a profiler trace: unit and role scopes of
 device ops, and host spans put on the device's clock.
-
-    python3 bench/scopes.py --workload <cell> --seed <n> [--seconds <s>] \
-        [--calls <n>] [--record <dir>]
-
-runs one cell on the TPU this process holds: set-up, an untraced window,
-then a traced window, and prints as its last line of standard output one
-JSON object with the per-role table, the host-device clock offset, the
-tracing overhead and the values :func:`read` computes.  ``--record``
-writes the traced window, trimmed, as a test fixture.
 
 The program names its work (see ``repro.runtime.executor``): unit ``i``
 of a CNN runs under the scope ``unitNN``, each of its ops under one role
@@ -28,16 +18,16 @@ approximate.  Ops with no unit or ``head`` scope count as ``other``.
 Host and device clocks differ by an offset that nothing records.
 :func:`align` bounds it by causality: call ``i``'s program cannot start
 on the device before the host enters ``executor.apply`` for it, nor end
-after the host returns from its ``bench.sync``.  With the offset, each
-idle stretch of the device is placed under the host span that covers it
-(:func:`idle_by_host`).
+after the host returns from its ``bench.sync``.  On the TPU v5e the
+interval is about half a millisecond wide, wider than a batch-1
+``executor.apply``, so device idle time cannot be placed under that span.
 
-This module builds on :mod:`bench.traces`, whose :func:`~bench.traces.
-extract` keeps the device plane; :func:`extract_host` adds the host's.
-It stands beside the harness only until the harness logs the role table
-itself: the reading functions then move into ``bench/traces.py`` and
-``bench/readers.py``, and :func:`measure`, :func:`main` and
-:func:`extract_host` are deleted.
+The harness reads a traced window through these functions
+(``bench.harness.MetricContext.from_trace``) and logs the per-role table
+(:func:`role_table`); the readers in ``bench/readers.py`` take their
+shares from it.  :func:`write_fixture` records a traced window, trimmed,
+as a test fixture: on the chip, build a ``bench.harness.Cell``, run its
+``traced_window`` and pass the trace's ``ProfileData`` here.
 """
 from __future__ import annotations
 
@@ -46,9 +36,8 @@ import dataclasses
 import json
 import os
 import re
-import statistics
-import sys
-import time
+
+from bench.traces import APPLY, CUSTOM_CALL, ISSUE, SYNC
 
 ROLES = ("pad", "lane_pad", "weight_prep", "relayout", "kernel", "crop",
          "epilogue")
@@ -56,7 +45,6 @@ LAYOUT = ("pad", "lane_pad", "weight_prep", "relayout", "crop")
 # roles of one instruction made from ops of several: all layout roles, or
 # anything else (a crop fused into an activation, say)
 LAYOUT_MIX, MIXED = "layout", "mixed"
-APPLY, ISSUE, SYNC = "executor.apply", "bench.issue", "bench.sync"
 OTHER = "other"
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{\s*$")
@@ -205,23 +193,6 @@ def role_table(roles: Roles, calls: int) -> list[str]:
 
 # -- host spans and the clock -------------------------------------------------
 
-def extract_host(trace) -> dict[str, list[list[float]]]:
-    """The host spans ``executor.apply``, ``bench.issue`` and
-    ``bench.sync`` of a trace (an ``.xplane.pb`` path or a
-    ``jax.profiler.ProfileData``): name → sorted ``[start_ns, dur_ns]``."""
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(trace) if isinstance(trace, str) else trace
-    spans: dict[str, list] = {APPLY: [], ISSUE: [], SYNC: []}
-    for plane in pd.planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name in spans:
-                        spans[e.name].append([e.start_ns, e.duration_ns])
-    return {k: sorted(v) for k, v in spans.items()}
-
-
 @dataclasses.dataclass
 class Alignment:
     """Host time = device time + ``offset_ns``, known to within the
@@ -257,127 +228,6 @@ def align(applies, syncs, modules) -> Alignment | None:
     return Alignment(offset_ns=(lo + hi) / 2, lo_ns=lo, hi_ns=hi)
 
 
-def _overlap(a, b) -> float:
-    """Total length common to two sorted lists of disjoint intervals."""
-    i = j = 0
-    total = 0.0
-    while i < len(a) and j < len(b):
-        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
-        if hi > lo:
-            total += hi - lo
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def _ends(spans):
-    return [[s, s + d] for s, d in spans]
-
-
-def idle_by_host(rec: dict, host: dict, al: Alignment | None) -> dict | None:
-    """Idle time of the first chip in the traced window (host clock, first
-    ``bench.issue`` or ``executor.apply`` to last ``bench.sync`` return),
-    by what the host was doing: ``executor.apply`` (dispatch),
-    ``bench.sync`` (waiting for, or returning from, the sync) or
-    ``host python`` (neither).  Seconds, plus ``window_s``; None without
-    an alignment."""
-    from bench.traces import _union
-
-    if al is None or not rec["devices"] or not host[SYNC]:
-        return None
-    starts = host[ISSUE] or host[APPLY]
-    w0 = starts[0][0]
-    w1 = max(s + d for s, d in host[SYNC])
-    off = al.offset_ns
-    busy = _union((s + off, s + d + off)
-                  for _, s, d in rec["devices"][0]["ops"])
-    idle, t = [], w0
-    for s, e in busy:
-        if s > t:
-            idle.append([t, min(s, w1)])
-        t = max(t, e)
-        if t >= w1:
-            break
-    if t < w1:
-        idle.append([t, w1])
-    idle = [iv for iv in idle if iv[1] > iv[0]]
-    total = sum(e - s for s, e in idle)
-    dispatch = _overlap(idle, _ends(host[APPLY]))
-    sync = _overlap(idle, _ends(host[SYNC]))
-    return {"window_s": (w1 - w0) * 1e-9, APPLY: dispatch * 1e-9,
-            SYNC: sync * 1e-9, "host python": (total - dispatch - sync)
-            * 1e-9}
-
-
-# -- what the proposed per-layer metrics would read ---------------------------
-
-def layout_share(roles: Roles):
-    """Per cent of device-op time scoped ``pad``, ``lane_pad``,
-    ``weight_prep``, ``relayout`` or ``crop``, or in an instruction made
-    from several of these."""
-    return roles.share(LAYOUT + (LAYOUT_MIX,))
-
-
-def epilogue_share(roles: Roles):
-    """Per cent of device-op time scoped ``epilogue``."""
-    return roles.share(("epilogue",))
-
-
-def mixed_share(roles: Roles):
-    """Per cent of device-op time in instructions made from ops of several
-    roles, not all of them layout roles (a crop fused into the
-    activation): neither :func:`layout_share` nor :func:`epilogue_share`
-    counts it."""
-    return roles.share((MIXED,))
-
-
-def dispatch_ms(host: dict):
-    """Median over traced calls of the ``executor.apply`` span (ms)."""
-    if not host[APPLY]:
-        return None
-    return statistics.median(d for _, d in host[APPLY]) * 1e-6
-
-
-def idle_in_dispatch_share(idle: dict | None):
-    """Per cent of the traced window with the device idle while the host,
-    on the aligned clock, is inside ``executor.apply``."""
-    if idle is None or idle["window_s"] <= 0:
-        return None
-    return 100.0 * idle[APPLY] / idle["window_s"]
-
-
-def read(rec: dict, host: dict, names: dict[str, str], calls: int) -> dict:
-    """Everything this module computes from one traced window."""
-    from bench.traces import CUSTOM_CALL
-
-    roles = role_seconds(rec, names)
-    modules = [[s, d] for _, s, d in rec["devices"][0]["modules"]] \
-        if rec["devices"] else []
-    al = align(host[APPLY], host[SYNC], modules)
-    idle = idle_by_host(rec, host, al)
-    kernels = [n for n, t in rec.get("custom_calls", {}).items()
-               if CUSTOM_CALL in t]
-    return {
-        "alignment": None if al is None else {
-            "offset_ms": al.offset_ns * 1e-6, "width_ms": al.width_ns * 1e-6},
-        "idle_by_host_s": idle,
-        "roles_ms_per_call": {r: s / calls * 1e3
-                              for r, s in sorted(roles.by_role.items())},
-        "unscoped_share": roles.share((OTHER,)),
-        "other_ops": roles.other_ops,
-        "kernels_named": bool(kernels) and all(
-            n.startswith(("merged_conv", "depthwise_conv")) for n in kernels),
-        "layout_share": layout_share(roles),
-        "epilogue_share": epilogue_share(roles),
-        "mixed_share": mixed_share(roles),
-        "dispatch_ms": dispatch_ms(host),
-        "idle_in_dispatch_share": idle_in_dispatch_share(idle),
-        "table": role_table(roles, calls),
-    }
-
-
 # -- a recorded window as a fixture -------------------------------------------
 
 _KEEP_LINES = ("XLA Ops", "XLA Modules")
@@ -394,8 +244,6 @@ def to_text_proto(pd) -> str:
     (op names other than Pallas calls cut to 100 characters) and the host
     spans ``executor.apply``, ``bench.issue``, ``bench.sync`` and
     ``PjitFunction``."""
-    from bench.traces import CUSTOM_CALL
-
     out = []
     for pid, plane in enumerate(pd.planes, 1):
         dev = plane.name.startswith("/device:TPU:")
@@ -435,8 +283,6 @@ def write_fixture(out_dir: str, name: str, pd, rec: dict, hlo: str,
     """``<name>.trace.pbtxt`` (:func:`to_text_proto`) and
     ``<name>.window.json`` (host-clock window, the compiled forward's
     Pallas call lines, the ``op_name`` of each traced op) in ``out_dir``."""
-    from bench.traces import CUSTOM_CALL
-
     os.makedirs(out_dir, exist_ok=True)
     base = os.path.join(out_dir, name)
     with open(base + ".trace.pbtxt", "w") as f:
@@ -455,100 +301,3 @@ def write_fixture(out_dir: str, name: str, pd, rec: dict, hlo: str,
                 if CUSTOM_CALL in ln),
             "op_names": {k: v for k, v in sorted(op_names(hlo).items())
                          if k in seen}}, f, indent=1)
-
-
-# -- one cell on the chip -----------------------------------------------------
-
-def measure(reg, name: str, seed: int, seconds: float, calls: int | None,
-            record: str | None, device: dict) -> dict:
-    """Set-up, an untraced and a traced window of one cell; the summary."""
-    import shutil
-    import tempfile
-
-    import numpy as np
-    from jax.profiler import ProfileData
-
-    from bench import traces
-    from bench.harness import Cell, Compiles, log
-
-    t0 = time.perf_counter()
-    compiles = Compiles()
-    cell = Cell(reg, name, seed)
-    setup_s = time.perf_counter() - t0
-    after_setup = dict(cell.ex.traces)
-    log(f"{name}: set-up {setup_s:.3f} s ({compiles}); executor traces "
-        f"{after_setup}")
-    lat, _, window_s = cell.window(seconds)
-    if dict(cell.ex.traces) != after_setup:
-        log(f"WARNING: the window traced: {dict(cell.ex.traces)}")
-    calls = calls or cell.traffic["trace_calls"]
-    tdir = tempfile.mkdtemp(prefix="bench_scopes_")
-    try:
-        hlo, path, tw = cell.traced_window(calls, tdir)
-        pd = ProfileData.from_file(path)
-        rec = traces.extract(pd)
-        host = extract_host(pd)
-        names = op_names(hlo)
-        if record:
-            write_fixture(record, name, pd, rec, hlo, tw, calls,
-                          device["kind"])
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-    red = traces.reduce(rec, tw, traces.kernel_kinds(hlo))
-    out = read(rec, host, names, calls)
-    for line in out.pop("table"):
-        log(f"{name}: role {line}")
-    log(f"{name}: host - device clock offset {out['alignment']}")
-    untraced = window_s / len(lat)
-    med = statistics.median(lat)
-    return {"cell": name, "device": device, "cell_setup_s": setup_s,
-            "compiles": str(compiles),
-            "executor_traces": {f"{k[0]} {k[1]}": v
-                                for k, v in cell.ex.traces.items()},
-            "untraced_ms": {"mean": untraced * 1e3, "median": med * 1e3,
-                            "p95": float(np.percentile(lat, 95)) * 1e3,
-                            "max": max(lat) * 1e3, "calls": len(lat),
-                            "calls_over_twice_median": sum(
-                                t > 2 * med for t in lat)},
-            "traced_ms": {"mean": tw / calls * 1e3, "calls": calls},
-            "tracing_overhead_pct": 100.0 * (tw / calls / untraced - 1),
-            "busy_share": red.busy_s / red.window_s if red.window_s else None,
-            "kernel_order": [k for k, _ in red.kernel_order], **out}
-
-
-def main(argv=None) -> int:
-    import argparse
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=3.0)
-    ap.add_argument("--calls", type=int, default=None,
-                    help="traced calls (default: the traffic's)")
-    ap.add_argument("--record", default=None,
-                    help="directory for the trimmed trace and its op_names")
-    args = ap.parse_args(argv)
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    sys.path.insert(1, os.path.join(root, "src"))
-    from bench.harness import Registry, configure_jax, log
-
-    import jax
-
-    reg = Registry(root)
-    configure_jax(root)
-    devs = jax.devices()
-    if devs[0].platform != "tpu":
-        log(f"needs a TPU; JAX found {devs[0].platform}")
-        return 2
-    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs)}
-    result = measure(reg, args.workload, args.seed, args.seconds,
-                     args.calls, args.record, device)
-    print(json.dumps(result), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

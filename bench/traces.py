@@ -4,17 +4,19 @@ A trace of the TPU holds, per chip, a plane ``/device:TPU:<n>`` whose line
 ``XLA Ops`` has one event per executed HLO instruction, named by the
 instruction's text (``%name = <shape> <opcode>(...)``), and whose line
 ``XLA Modules`` has one event per executed program (here: one per call).
-The harness's host spans (``bench.issue``, ``bench.sync``) are on
-``/host:CPU``; host and device clocks are not aligned to better than
-about a millisecond, so idle gaps are placed by the device's own
-structure: between two programs the host was returning from one call's
-sync and issuing the next, inside one it was waiting in the sync.
+The harness's host spans (``bench.issue``, ``bench.sync``) and the
+program's (``executor.apply``) are on ``/host:CPU``.  Host and device
+clocks differ by an offset that nothing records, so :func:`reduce` places
+idle gaps by the device's own structure: between two programs the host
+was returning from one call's sync and issuing the next, inside one it
+was waiting in the sync.  (:func:`bench.scopes.align` bounds the offset,
+to about half a millisecond, from the same spans.)
 
 :func:`extract` turns a trace file into a small JSON-able record;
 :func:`reduce` computes from that record what the per-layer metrics read.
 Pallas kernels are the ``tpu_custom_call`` instructions of the compiled
-forward; which kernel each one is comes from its instruction text (see
-:func:`kernel_kind`), since the kernels carry no names of their own.
+forward; which kernel each one is comes from its name, or else from its
+instruction text (see :func:`kernel_kind`).
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ KERNELS = ("merged_conv", "depthwise_conv")
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 BETWEEN_CALLS = "between calls: host returns from bench.sync, runs bench.issue"
 EDGES = "window edges: first issue, last bench.sync return"
+APPLY, ISSUE, SYNC = "executor.apply", "bench.issue", "bench.sync"
 
 _INSTR = re.compile(r"^\s*%?([\w.\-]+) = ")
 _DIMS = re.compile(r"\b[a-z][a-z0-9]*\[([\d,]*)\]")
@@ -108,14 +111,23 @@ def _instr(event_name: str) -> str:
 
 
 def extract(trace) -> dict:
-    """The parts of a trace that :func:`reduce` reads; ``trace`` is an
-    ``.xplane.pb`` path or a ``jax.profiler.ProfileData``."""
+    """The parts of a trace that the readers use; ``trace`` is an
+    ``.xplane.pb`` path or a ``jax.profiler.ProfileData``.  ``devices``
+    and ``custom_calls`` are what :func:`reduce` reads; ``host`` holds the
+    host spans :data:`APPLY`, :data:`ISSUE` and :data:`SYNC`, each a sorted
+    list of ``[start_ns, dur_ns]``."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(trace) if isinstance(trace, str) else trace
     devices, custom = [], {}
+    host: dict[str, list] = {APPLY: [], ISSUE: [], SYNC: []}
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in host:
+                        host[e.name].append([e.start_ns, e.duration_ns])
+        elif plane.name.startswith("/device:TPU:"):
             ops, modules = [], []
             for line in plane.lines:
                 if line.name == "XLA Ops":
@@ -130,7 +142,8 @@ def extract(trace) -> dict:
             if ops:
                 devices.append({"plane": plane.name, "ops": ops,
                                 "modules": modules})
-    return {"devices": devices, "custom_calls": custom}
+    return {"devices": devices, "custom_calls": custom,
+            "host": {k: sorted(v) for k, v in host.items()}}
 
 
 @dataclasses.dataclass

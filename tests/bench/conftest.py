@@ -57,3 +57,46 @@ def make_root(dst, configs=("tiny_resnet", "tiny_mobilenet")):
 @pytest.fixture
 def tiny_root(tmp_path):
     return make_root(tmp_path)
+
+
+FAMILY_CELL = "tiny_refine.steps2"
+
+
+def make_family_root(dst):
+    """A checkout root holding the benchmark plus a cell of a family that
+    is not a CNN (``data/refine.py``, two forwards a call), added as a
+    later change would add it: a family module, a configuration, a plan
+    and a traffic mix as new files, and new entries in BENCHMARK.json."""
+    make_root(dst, configs=())
+    bench = os.path.join(dst, "bench")
+    shutil.copy(os.path.join(DATA, "refine.py"), bench)
+    for name in ("tiny_refine.json", "tiny_refine.plan.json"):
+        shutil.copy(os.path.join(DATA, name), os.path.join(bench, "configs"))
+    shutil.copy(os.path.join(DATA, "tiny_steps2.json"),
+                os.path.join(bench, "workloads"))
+    with open(os.path.join(dst, "BENCHMARK.json")) as f:
+        bm = json.load(f)
+    bm["configs"].append({"name": "tiny_refine", "source": "test",
+                          "file": "bench/configs/tiny_refine.json",
+                          "reduced": [], "why": "test"})
+    bm["workloads"].append({"name": FAMILY_CELL, "config": "tiny_refine",
+                            "traffic": "tiny_steps2", "chips": 1,
+                            "why": "test"})
+    for m in bm["end_to_end"] + bm["per_layer"]:
+        if m["name"] in ("images_per_s", "mfu"):
+            m["workloads"].append(FAMILY_CELL)
+    with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+    return str(dst)
+
+
+@pytest.fixture
+def family_root(tmp_path, monkeypatch):
+    """:func:`make_family_root`, with its ``bench/`` searched for family
+    modules after the repository's, as a checkout's own ``bench/`` is."""
+    import bench
+    root = make_family_root(tmp_path)
+    monkeypatch.setattr(bench, "__path__",
+                        [*bench.__path__, os.path.join(root, "bench")])
+    yield root
+    sys.modules.pop("bench.refine", None)

@@ -5,13 +5,14 @@ every cell can be found by name, that names and units keep to the
 characters the benchmark's readers accept, and that the numbers pinned in
 the configuration files still follow from the stored plans.
 """
+import importlib
 import json
 import os
 import re
 
 import pytest
 
-from bench import cnn, cost, harness
+from bench import harness
 
 ROOT = harness.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
@@ -83,14 +84,22 @@ def test_names_unique():
 
 def test_end_to_end_metrics():
     names = {m["name"]: m for m in BM["end_to_end"]}
-    assert set(names) == {"images_per_s", "latency_ms_p95", "setup_s"}
+    assert set(names) == {"images_per_s", "latency_ms_p95",
+                          "latency_ms_mean", "setup_s"}
     for m in BM["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
-    assert names["latency_ms_p95"]["workloads"] == ["resnet34.online_b1"]
-    assert "resnet34.online_b1" not in names["images_per_s"]["workloads"]
+    # a cell is offline (throughput) or online (latency), never both;
+    # latency is that of single-image calls, its tail beside its mean over
+    # the whole window, which a stall of the window moves
+    online = names["latency_ms_p95"]["workloads"]
+    assert not set(online) & set(names["images_per_s"]["workloads"])
+    assert names["latency_ms_mean"]["workloads"] == online
+    reg = harness.Registry(ROOT)
+    for cell in online:
+        assert reg.traffic(reg.cell(cell)["traffic"])["batch"] == 1
 
 
 @pytest.mark.parametrize("m", BM["per_layer"], ids=lambda m: m["name"])
@@ -140,30 +149,45 @@ def test_config_file(entry):
     assert files.count(entry["file"]) == 1
     cfg = harness.Registry(ROOT).config(entry["name"])
     assert cfg["source"] == entry["source"]
-    assert cfg["reduced"] == entry["reduced"] == []
+    assert cfg["reduced"] == entry["reduced"]
     assert len(entry["reduced"]) <= 16
+    assert all(NAME.match(k) for k in entry["reduced"])
+
+
+def _family(cfg):
+    return importlib.import_module(f"bench.{cfg['family']}")
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_pinned_work_follows_from_plan(name):
-    """``work`` in the configuration file is what the cost function counts
-    from the stored plan at unpadded shapes."""
+    """``work`` in the configuration file is what the family counts from
+    the stored plan (for CNNs ``bench.cost.work``, at unpadded shapes)."""
     cfg = harness.Registry(ROOT).config(name)
-    assert cfg["work"] == cost.work(cfg, cfg["plan_json"])
+    assert cfg["work"] == _family(cfg).work(cfg, cfg["plan_json"])
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_config_layers_are_the_programs(name):
-    """The configuration's layer list is the program's zoo network, and its
-    stored plan is one the program accepts."""
-    from repro.core.plan import CompressionPlan
-
+    """The configuration's layers are the program's network and its stored
+    plan is one the program accepts (the family's ``check_config``); the
+    plan was made by the program from tables timed on the chip."""
     cfg = harness.Registry(ROOT).config(name)
-    net = cnn._zoo_net(cfg)
-    plan = CompressionPlan.from_json(cfg["plan_text"])
-    assert plan.num_layers == net.L == len(cfg["layers"])
+    _family(cfg).check_config(cfg)
     prov = cfg["plan_json"]["provenance"]
     assert prov["predicted_speedup"] > 1 and "wallclock" in prov["command"]
+
+
+def test_cnn_family_is_todays_counts_and_checks():
+    """For CNNs the family's work is ``bench.cost.work``, and its check
+    refuses a plan over another number of layers."""
+    from bench import cnn, cost
+
+    cfg = harness.Registry(ROOT).config("resnet34")
+    assert cnn.work(cfg, cfg["plan_json"]) == cost.work(cfg, cfg["plan_json"])
+    short = json.loads(cfg["plan_text"])
+    short["num_layers"] -= 1
+    with pytest.raises(ValueError):
+        cnn.check_config({**cfg, "plan_text": json.dumps(short)})
 
 
 def test_unknown_device_kind_is_an_error():

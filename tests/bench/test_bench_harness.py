@@ -39,6 +39,30 @@ def test_run_prints_the_contract_line(tiny_root, cell):
     json.dumps(r)
 
 
+def test_latency_cell_reports_its_tail_and_its_mean(tiny_root):
+    """A cell listed under the latency metrics reports the 95th percentile
+    of its calls and the window's length over its calls."""
+    bm_path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(bm_path) as f:
+        bm = json.load(f)
+    for m in bm["end_to_end"]:
+        if m["name"] in ("latency_ms_p95", "latency_ms_mean"):
+            m["workloads"].append("tiny_resnet.b4")
+        elif m["name"] == "images_per_s":
+            m["workloads"].remove("tiny_resnet.b4")
+    with open(bm_path, "w") as f:
+        json.dump(bm, f)
+    r = _run(tiny_root, "tiny_resnet.b4")
+    assert r["correct"] is True
+    assert set(r["metrics"]) == {"latency_ms_p95", "latency_ms_mean",
+                                 "setup_s"}
+    mean = r["metrics"]["latency_ms_mean"]
+    assert mean["unit"] == "ms"
+    # the window closes with the first call to end 0.2 s after its start
+    assert mean["value"] * r["attempted"] >= 200
+    assert r["metrics"]["latency_ms_p95"]["value"] > 0
+
+
 def test_traced_run_reads_the_added_metric(tiny_root):
     """A per-layer metric is added as one new reader file and an entry in
     BENCHMARK.json.  A CPU trace has no TPU plane: the readers of device

@@ -1,21 +1,28 @@
 """Unit and role scopes of device ops, and host spans on the device's
-clock (``bench/scopes.py``).
+clock (``bench/scopes.py``), as the harness hands them to the readers.
 
-The synthetic cases plant what each function should find.  The CPU run
-drives ``scopes.measure`` on a tiny cell: a CPU trace has no TPU plane,
-so only the host spans are read.  The last tests read a small trace
-recorded on the chip with the program's names in it.
+The synthetic cases plant what each function should find.  The CPU runs
+drive the harness's traced window on a tiny cell: a CPU trace has no TPU
+plane, so only the host spans are read.  The last tests read a small
+trace recorded on the chip with the program's names in it.
 """
 import json
 import os
+import time
 
 import pytest
 
-from bench import harness, scopes, traces
+from bench import harness, readers, scopes, traces
 
 from conftest import CPU
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+def _ctx(**kw):
+    """A metric context with only the program's names filled in."""
+    return harness.MetricContext(trace=None, work={}, peak={}, batch=1,
+                                 calls=1, images_per_s=0.0, chips=1, **kw)
 
 
 @pytest.mark.parametrize("op_name,want", [
@@ -125,9 +132,9 @@ def test_role_seconds_and_shares():
                          "kernel": pytest.approx(20e-6)}
     assert r.by_unit["kernel"] == {"unit00": pytest.approx(20e-6)}
     assert r.other_ops == [["copy.1", pytest.approx(4e-6)]]
-    assert scopes.layout_share(r) == pytest.approx(25.0)
-    assert scopes.epilogue_share(r) == pytest.approx(0.0)
-    assert scopes.mixed_share(r) == pytest.approx(0.0)
+    assert readers.layout_share(_ctx(roles=r)) == pytest.approx(25.0)
+    assert readers.epilogue_share(_ctx(roles=r)) == pytest.approx(0.0)
+    assert r.share([scopes.MIXED]) == pytest.approx(0.0)
     (line,) = [ln for ln in scopes.role_table(r, calls=2)
                if ln.startswith("lane_pad")]
     assert "0.0040 ms/call" in line and "unit00 0.0040" in line
@@ -144,17 +151,18 @@ def test_mixed_and_layout_fusions_count_apart():
     assert r.by_role == {"layout": pytest.approx(8e-6),
                          "mixed": pytest.approx(4e-6),
                          "kernel": pytest.approx(20e-6)}
-    assert scopes.layout_share(r) == pytest.approx(25.0)
-    assert scopes.mixed_share(r) == pytest.approx(12.5)
-    assert scopes.epilogue_share(r) == pytest.approx(0.0)
+    assert readers.layout_share(_ctx(roles=r)) == pytest.approx(25.0)
+    assert r.share([scopes.MIXED]) == pytest.approx(12.5)
+    assert readers.epilogue_share(_ctx(roles=r)) == pytest.approx(0.0)
     assert any(ln.startswith("mixed") for ln in scopes.role_table(r, 2))
 
 
 def test_no_ops_read_nothing():
     r = scopes.role_seconds({"devices": []}, {})
-    assert scopes.layout_share(r) is None
-    assert scopes.epilogue_share(r) is None
-    assert scopes.mixed_share(r) is None
+    for ctx in (_ctx(roles=r), _ctx()):
+        assert readers.layout_share(ctx) is None
+        assert readers.epilogue_share(ctx) is None
+    assert r.share([scopes.MIXED]) is None
 
 
 def _host(offset, dispatch=3_000, sync_tail=2_000, calls=(0, 40_000)):
@@ -197,28 +205,12 @@ def test_align_gives_none_on_an_empty_interval_or_unpaired_calls():
     assert scopes.align([], [], []) is None
 
 
-def test_idle_is_placed_under_the_host_span_that_covers_it():
-    rec, h = _record(), _host(5_000)
-    mods = [[s, d] for _, s, d in rec["devices"][0]["modules"]]
-    al = scopes.align(h[scopes.APPLY], h[scopes.SYNC], mods)
-    idle = scopes.idle_by_host(rec, h, al)
-    # window: first issue (t = 1.5 µs) to last sync return (63 µs)
-    assert idle["window_s"] == pytest.approx(61.5e-6)
-    busy = 32e-6
-    total = idle[scopes.APPLY] + idle[scopes.SYNC] + idle["host python"]
-    assert total == pytest.approx(idle["window_s"] - busy)
-    assert idle[scopes.APPLY] > 0 and idle[scopes.SYNC] > 0
-    share = scopes.idle_in_dispatch_share(idle)
-    assert share == pytest.approx(100 * idle[scopes.APPLY] / 61.5e-6)
-    assert scopes.idle_by_host(rec, h, None) is None
-    assert scopes.idle_in_dispatch_share(None) is None
-
-
 def test_dispatch_ms_is_the_median_apply_span():
     h = _host(0)
     h[scopes.APPLY].append([90_000, 9_000])
-    assert scopes.dispatch_ms(h) == pytest.approx(3.2e-3)
-    assert scopes.dispatch_ms({scopes.APPLY: []}) is None
+    assert readers.dispatch_ms(_ctx(host=h)) == pytest.approx(3.2e-3)
+    assert readers.dispatch_ms(_ctx(host={scopes.APPLY: []})) is None
+    assert readers.dispatch_ms(_ctx()) is None
 
 
 def test_text_proto_round_trip_keeps_what_the_reductions_read():
@@ -232,29 +224,77 @@ def test_text_proto_round_trip_keeps_what_the_reductions_read():
     assert [o[0] for o in da["ops"]] == [o[0] for o in db["ops"]]
     for x, y in zip(da["ops"] + da["modules"], db["ops"] + db["modules"]):
         assert x[1:] == pytest.approx(y[1:], abs=1e-3)
-    ha, hb = scopes.extract_host(pd), scopes.extract_host(again)
+    ha, hb = a["host"], b["host"]
     assert len(ha[scopes.ISSUE]) == len(hb[scopes.ISSUE]) == 2
     for x, y in zip(ha[scopes.ISSUE] + ha[scopes.SYNC],
                     hb[scopes.ISSUE] + hb[scopes.SYNC]):
         assert x == pytest.approx(y, abs=1e-3)
 
 
-def test_measure_on_the_cpu_reads_the_host_spans(tiny_root, tmp_path):
-    """The chip-side tool end to end at a toy size: the host spans are
-    there, the device readings are not (no TPU plane), and the recorded
-    window reads back."""
+def test_traced_window_on_the_cpu_reads_the_host_spans(tiny_root,
+                                                       tmp_path):
+    """The harness's traced window at a toy size: the host spans are
+    there, the device readings are not (no TPU plane), the window traced
+    no new program, and the recorded window reads back."""
+    import glob
+    import shutil
+    import tempfile
+
     from jax.profiler import ProfileData
-    reg = harness.Registry(tiny_root)
-    out = scopes.measure(reg, "tiny_resnet.b4", 2**33 + 5, 0.2, 3,
-                         str(tmp_path), dict(CPU))
-    assert out["executor_traces"] == {"apply (4, 16, 16, 3)": 1}
-    assert out["traced_ms"]["calls"] == 3
-    assert out["dispatch_ms"] > 0
-    assert out["alignment"] is None and out["layout_share"] is None
-    assert out["idle_in_dispatch_share"] is None
-    with open(tmp_path / "tiny_resnet.b4.trace.pbtxt") as f:
-        host = scopes.extract_host(ProfileData.from_text_proto(f.read()))
+    cell = harness.Cell(harness.Registry(tiny_root), "tiny_resnet.b4",
+                        2**33 + 5)
+    traced = dict(cell.ex.traces)
+    assert traced == {("apply", (4, 16, 16, 3)): 1}
+    tdir = tempfile.mkdtemp(dir=tmp_path)
+    try:
+        hlo, path, tw = cell.traced_window(3, tdir)
+        pd = ProfileData.from_file(path)
+        rec = traces.extract(pd)
+        scopes.write_fixture(str(tmp_path), "tiny", pd, rec, hlo, tw, 3,
+                             "cpu")
+    finally:
+        shutil.rmtree(tdir)
+    assert dict(cell.ex.traces) == traced
+    ctx = harness.MetricContext.from_trace(
+        rec, tw, traces.kernel_kinds(hlo), scopes.op_names(hlo),
+        work={}, peak={}, batch=4, calls=3, images_per_s=1.0, chips=1)
+    assert len(rec["host"][scopes.APPLY]) == 3
+    assert readers.dispatch_ms(ctx) > 0
+    assert ctx.alignment is None
+    assert readers.layout_share(ctx) is None
+    (txt,) = glob.glob(str(tmp_path / "tiny.trace.pbtxt"))
+    with open(txt) as f:
+        host = traces.extract(ProfileData.from_text_proto(f.read()))["host"]
     assert len(host[scopes.APPLY]) == len(host[scopes.SYNC]) == 3
+    with open(tmp_path / "tiny.window.json") as f:
+        assert json.load(f)["calls"] == 3
+
+
+def test_traced_run_reports_the_dispatch_span(tiny_root):
+    """A cell listed under ``executor.dispatch_ms.online`` reports it from
+    the program's ``executor.apply`` span; the readers of device time
+    find no TPU plane here and leave their metrics out."""
+    bm_path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(bm_path) as f:
+        bm = json.load(f)
+    for m in bm["per_layer"]:
+        if m["name"] in ("executor.dispatch_ms.online",
+                         "executor.layout_share"):
+            m["workloads"].append("tiny_resnet.b4")
+    with open(bm_path, "w") as f:
+        json.dump(bm, f)
+    path = os.path.join(tiny_root, "bench", "peaks.json")
+    with open(path) as f:
+        peaks = json.load(f)
+    peaks["devices"]["cpu"] = peaks["devices"]["TPU v5 lite"]
+    with open(path, "w") as f:
+        json.dump(peaks, f)
+    r = harness.run(harness.Registry(tiny_root), "tiny_resnet.b4", 2**33 + 5,
+                    0.2, True, time.perf_counter(), dict(CPU))
+    assert r["correct"] is True
+    assert 0 < r["metrics"]["executor.dispatch_ms.online"]["value"]
+    assert r["metrics"]["executor.dispatch_ms.online"]["unit"] == "ms"
+    assert "executor.layout_share" not in r["metrics"]
 
 
 # -- the chip trace with the program's names ---------------------------------
@@ -273,7 +313,7 @@ def scoped():
     with open(base + ".window.json") as f:
         win = json.load(f)
     rec = traces.extract(pd)
-    return rec, scopes.extract_host(pd), win
+    return rec, rec["host"], win
 
 
 def test_scoped_trace_names_every_kernel(scoped):
@@ -306,16 +346,18 @@ def test_scoped_trace_roles(scoped):
     assert roles.share(("other",)) < 5
     stem = roles.by_unit["kernel"]["unit00"]
     assert stem > 0.5 * roles.by_role["kernel"]
-    out = scopes.read(rec, host, win["op_names"], win["calls"])
-    assert 0 < out["layout_share"] < 100 and 0 < out["epilogue_share"] < 100
-    assert 0 < out["mixed_share"] < out["epilogue_share"]
-    assert out["kernels_named"] is True
+    ctx = _scoped_ctx(rec, win)
+    layout, epilogue = readers.layout_share(ctx), readers.epilogue_share(ctx)
+    assert 0 < layout < 100 and 0 < epilogue < 100
+    assert 0 < ctx.roles.share([scopes.MIXED]) < epilogue
+    assert all(n.startswith(("merged_conv", "depthwise_conv"))
+               for n in rec["custom_calls"])
 
 
 def test_scoped_trace_alignment(scoped):
     """After alignment each call's program lies between the host entering
-    its executor.apply and leaving its bench.sync, and the idle time of
-    the window splits without remainder over the host spans."""
+    its executor.apply and leaving its bench.sync; the context carries the
+    same alignment."""
     rec, host, win = scoped
     (dev,) = rec["devices"]
     mods = [[s, d] for _, s, d in dev["modules"]]
@@ -324,12 +366,28 @@ def test_scoped_trace_alignment(scoped):
     for (a, _), (s, sd), (m, md) in zip(host[scopes.APPLY],
                                         host[scopes.SYNC], mods):
         assert a <= m + al.offset_ns <= m + md + al.offset_ns <= s + sd
-    idle = scopes.idle_by_host(rec, host, al)
-    busy = sum(e - s for s, e in traces._union(
-        (s, s + d) for _, s, d in dev["ops"])) * 1e-9
-    parts = idle[scopes.APPLY] + idle[scopes.SYNC] + idle["host python"]
-    assert parts == pytest.approx(idle["window_s"] - busy, rel=1e-6)
-    assert idle["window_s"] == pytest.approx(win["window_s"], rel=0.05)
-    share = scopes.idle_in_dispatch_share(idle)
-    assert 0 < share < 100
-    assert 0 < scopes.dispatch_ms(host) < 1.0
+    ctx = _scoped_ctx(rec, win)
+    assert ctx.alignment == al
+    assert 0 < readers.dispatch_ms(ctx) < 1.0
+
+
+def _scoped_ctx(rec, win):
+    cfg = harness.Registry().config("resnet34")
+    return harness.MetricContext.from_trace(
+        rec, win["window_s"], traces.kernel_kinds(win["custom_calls"]),
+        win["op_names"], work=cfg["work"],
+        peak=harness.peak_of("TPU v5 lite"), batch=1, calls=win["calls"],
+        images_per_s=500.0, chips=1)
+
+
+def test_scoped_trace_reads_every_online_metric(scoped):
+    """Every per-layer metric of the online cells reads a number from the
+    recorded window, shares and rooflines within (0, 100]."""
+    rec, _, win = scoped
+    reg = harness.Registry()
+    ctx = _scoped_ctx(rec, win)
+    for m in reg.metrics("per_layer", "resnet34.online_b1"):
+        v = reg.reader(m["name"])(ctx)
+        assert v is not None and v > 0, m["name"]
+        if m["unit"] == "%":
+            assert v <= 100, (m["name"], v)

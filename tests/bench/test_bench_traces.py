@@ -12,7 +12,7 @@ import os
 
 import pytest
 
-from bench import cost, harness, traces
+from bench import cost, harness, readers, traces
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -146,3 +146,58 @@ def test_chip_trace_on_chip_memory(chip):
     text = ("%k = f32[8,4]{1,0:T(8,128)} custom-call(f32[8,4]{1,0:T(8,128)"
             "S(1)} %a, f32[8,4]{1,0} %b), custom_call_target=\"x\"")
     assert traces.hbm_fraction(text) == pytest.approx(2 / 3)
+
+
+# -- forwards per call --------------------------------------------------------
+
+UNITS = [{"unit": "conv0_1", "kernel": "merged_conv", "flops": 4_000_000,
+          "bytes": 40_000, "weight_bytes": 9_000},
+         {"unit": "proj0_1", "kernel": None, "flops": 1_000, "bytes": 800,
+          "weight_bytes": 100},
+         {"unit": "conv1_2", "kernel": "depthwise_conv", "flops": 90_000,
+          "bytes": 600_000, "weight_bytes": 400}]
+
+
+def _forward_ctx(forwards, order, scale=1.0):
+    """Three calls of ``forwards`` forwards of :data:`UNITS` at batch 2:
+    ``scale`` times one forward's kernel time, and images per second in
+    proportion to one over the forwards."""
+    red = traces.Reduced(
+        window_s=1.0, busy_s=0.5, op_s=0.5,
+        kernel_s={"merged_conv": 3e-6 * scale, "depthwise_conv": 2e-6 * scale},
+        top_ops=[], idle_gaps=[], kernel_order=order)
+    return harness.MetricContext(
+        trace=red, work={"units": UNITS, "flops_per_image": 4_091_000},
+        peak=harness.peak_of("TPU v5 lite"), batch=2, calls=3,
+        images_per_s=400.0 / forwards, chips=1, forwards=forwards)
+
+
+ONE = [("merged_conv", 1.0), ("depthwise_conv", 0.25)]
+
+
+def test_readers_count_each_forward_once():
+    """Two forwards a call, twice the kernel time and the kernel order
+    repeated read as one forward does; so does MFU at half the images per
+    second."""
+    reg = harness.Registry()
+    one, two = _forward_ctx(1, ONE), _forward_ctx(2, ONE * 2, scale=2.0)
+    for m in ("merged_conv_roofline", "depthwise_conv_roofline", "mfu"):
+        a, b = reg.reader(m)(one), reg.reader(m)(two)
+        assert a is not None and b == pytest.approx(a, rel=1e-12), m
+    bound = cost.kernel_bound_seconds(UNITS, "depthwise_conv", 2,
+                                      one.peak, [1.0, 0.25])
+    assert reg.reader("depthwise_conv_roofline")(one) == \
+        100 * 3 * bound / 2e-6
+
+
+@pytest.mark.parametrize("forwards,order", [
+    (2, ONE),                                   # one forward's kernels
+    (1, ONE * 2),                               # two where one is planned
+    (2, ONE + ONE[::-1]),                       # the second out of order
+    (2, ONE + ONE[:1]),                         # a kernel missing
+])
+def test_misaligned_kernel_order_reads_none(forwards, order):
+    ctx = _forward_ctx(forwards, order, scale=forwards)
+    for kernel in ("merged_conv", "depthwise_conv"):
+        assert readers.kernel_roofline(ctx, kernel) is None
+
