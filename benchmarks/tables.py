@@ -17,6 +17,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import (AnalyticTPUOracle, ImportanceSpec, WallClockOracle,
                         accuracy_perf, compress, merge, table_entry_count,
@@ -155,21 +156,23 @@ def table23_mobilenetv2():
 
 
 def table45_ddpm():
-    """DDPM path: denoising objective on the skip-concat UNet (FID is not
-    computable offline; eval = denoising MSE, lower is better)."""
-    net = zoo.tiny_unet(in_hw=16, base=8)
+    """DDPM path: denoising objective on the time-conditioned UNet at toy
+    width (FID is not computable offline; eval = denoising MSE, lower is
+    better)."""
+    net = zoo.ddpm_unet(in_hw=16, ch=16, ch_mult=(1, 2), num_res_blocks=1,
+                        attn_resolutions=(8,), gn_groups=8)
     params = cnn.init_params(net, jax.random.PRNGKey(0))
-    key = jax.random.PRNGKey(3)
+    abar = jnp.asarray(np.cumprod(1 - np.linspace(1e-4, 0.02, 1000)),
+                       jnp.float32)
 
     def diffusion_batch(key, n=64):
         k1, k2, k3 = jax.random.split(key, 3)
         img = jax.random.normal(k1, (n, 16, 16, 3))
-        t = jax.random.uniform(k2, (n, 1, 1, 1))
+        t = jax.random.randint(k2, (n,), 0, 1000)
         noise = jax.random.normal(k3, (n, 16, 16, 3))
-        noisy = jnp.sqrt(1 - t) * img + jnp.sqrt(t) * noise
-        inp = jnp.concatenate([noisy, jnp.broadcast_to(t, (n, 16, 16, 1))],
-                              axis=-1)
-        return inp, noise
+        a = abar[t][:, None, None, None]
+        noisy = jnp.sqrt(a) * img + jnp.sqrt(1 - a) * noise
+        return (noisy, t), noise
 
     tr = diffusion_batch(jax.random.PRNGKey(1))
     ev = diffusion_batch(jax.random.PRNGKey(2))
@@ -284,7 +287,7 @@ def table78_cost():
     rows = []
     for name, net in (("resnet34", zoo.resnet34()),
                       ("mobilenetv2", zoo.mobilenetv2()),
-                      ("ddpm_unet", zoo.ddpm_unet())):
+                      ("ddpm_cifar10", zoo.ddpm_unet())):
         params = None
         host = cnn_host.CNNHost(net, {"layers": [{} for _ in net.specs],
                                       "skips": [], "head": {}}, batch=128)

@@ -30,10 +30,12 @@ CNN_ARCHS = {
         num_classes=4, in_hw=16, width=8, blocks=(2, 2)),
     "tiny_mobilenet": lambda zoo: zoo.tiny_mobilenet(
         num_classes=4, in_hw=16, width=8),
-    "tiny_unet": lambda zoo: zoo.tiny_unet(in_hw=16, base=8),
+    "tiny_ddpm": lambda zoo: zoo.ddpm_unet(
+        in_hw=16, ch=16, ch_mult=(1, 2), num_res_blocks=1,
+        attn_resolutions=(8,), gn_groups=8),
     "resnet34": lambda zoo: zoo.resnet34(),
     "mobilenetv2": lambda zoo: zoo.mobilenetv2(),
-    "ddpm_unet": lambda zoo: zoo.ddpm_unet(),
+    "ddpm_cifar10": lambda zoo: zoo.ddpm_unet(),
 }
 
 

@@ -130,11 +130,20 @@ def merge_conv_chain(weights, strides, depthwise_flags):
 
 def merge_bias_through(w2: jax.Array, b1: jax.Array, b2: jax.Array | None,
                        dw2: bool = False) -> jax.Array:
-    """Bias of ``conv2 ∘ (conv1 + b1)``: ``b2 + Σ_spatial w2 · b1``."""
+    """Bias of ``conv2 ∘ (conv1 + b1)``: ``b2 + S2ᵀ b1``, where ``S2 =
+    Σ_spatial w2`` is conv2's tap sum.
+
+    ``b1`` may carry leading axes: a per-image bias ``c`` of shape
+    ``(N, C)`` (DDPM's time bias), or the weight ``(D, C)`` of the Dense
+    that makes it.  The fold is exact wherever conv2 sees the bias as a
+    spatially constant field, which holds in a merged segment: it pads
+    once in front and runs every conv VALID, so
+    ``conv2(conv1(x) + b1 + c[n]) + b2 = merged(x) + b2 + S2ᵀ(b1 + c[n])``.
+    """
     if dw2:
-        contrib = jnp.sum(w2, axis=(0, 1))[0] * b1      # (c,)
+        contrib = jnp.sum(w2, axis=(0, 1))[0] * b1      # (..., c)
     else:
-        contrib = jnp.einsum("hwio,i->o", w2, b1,
+        contrib = jnp.einsum("hwio,...i->...o", w2, b1,
                              precision=lax.Precision.HIGHEST)
     return contrib if b2 is None else b2 + contrib
 

@@ -7,7 +7,9 @@ annotations.  The same definition can be *applied* three ways:
 * replaced (pruned, unmerged) — ``apply_replaced(net, params, x, plan)``:
   activations outside ``A`` are dropped, convs outside ``C`` become the
   identity, padding is re-ordered to the front of every merged group
-  (paper Appendix A), GroupNorms are moved to group ends;
+  (paper Appendix A), GroupNorms are moved to group ends (in a
+  pre-activation net, below, the GroupNorm before a dropped activation
+  goes with it);
 * merged              — ``CNNHost.lower_plan(plan, params)`` folds every
   segment into a single convolution (Eq. 1 composition via
   :func:`merge_segment`: BN folding, skip-add Dirac fusion) and lowers
@@ -21,6 +23,20 @@ is the cornerstone of the paper's method.
 
 Skip blocks may carry a projection shortcut (ResNet downsample blocks);
 those blocks cannot be Dirac-fused, so spans may only sit *inside* them.
+
+A *pre-activation* net (``ConvNet.preact``, the DDPM UNet of
+:func:`repro.models.zoo.ddpm_unet`) reads ``norm``/``act`` of a conv as
+acting on the conv's input: ``conv(act(GN(x)))``.  The nonlinearity kept
+at a segment boundary ``i`` is then layer ``i+1``'s GroupNorm and
+activation, applied at the start of the segment to its branch, while
+boundaries hold raw values: a residual add reads the raw block input.
+Residual adds are never Dirac-fused there, and multi-layer spans lie
+inside one residual block.  A time-conditioned net (``temb_ch > 0``)
+takes the pair ``(x, t)``: a sinusoidal embedding of the integer
+timesteps ``t`` through a two-layer MLP, whose SiLU feeds one Dense per
+``temb`` conv, added after that conv as a per-image bias ``(N, C)``.  A
+merged segment carries that bias folded through its later kept convs
+(:func:`segment_time_bias`); a pruned conv takes its bias with it.
 """
 from __future__ import annotations
 
@@ -53,6 +69,8 @@ class ConvSpec:
     gn_groups: int = 8
     bias: bool = True
     kind: str = "conv"           # 'conv' | 'pool' (avg) | 'upsample' | 'attn'
+    temb: bool = False           # a per-image time bias follows the conv
+    gn_eps: float = 1e-5
 
     @property
     def shape_preserving(self) -> bool:
@@ -77,6 +95,9 @@ class ConvNet:
     head: str = "classifier"     # 'classifier' | 'none'
     num_classes: int = 10
     act_after_merge: bool = False   # paper's MobileNetV2 trick (Appendix A)
+    preact: bool = False         # norm/act of a conv act on its input
+    temb_dim: int = 0            # sinusoidal timestep embedding width
+    temb_ch: int = 0             # time-MLP width; 0: not time-conditioned
 
     @property
     def L(self) -> int:
@@ -86,10 +107,21 @@ class ConvNet:
         return self.specs[l - 1]
 
     # -- compressibility metadata -------------------------------------------
+    def _in_block(self, i: int, j: int) -> bool:
+        """Layers i+1..j lie inside one residual-add block."""
+        return any(sk.kind == "add" and sk.start <= i and j <= sk.end
+                   for sk in self.skips)
+
+    def prunable(self, l: int) -> bool:
+        """A shape-preserving conv; in a pre-activation net, one inside a
+        residual block (the others are barriers)."""
+        return self.spec(l).shape_preserving and (
+            not self.preact or self._in_block(l - 1, l))
+
     def irreducible(self) -> tuple[int, ...]:
-        """R — layers whose input/output shapes differ (plus non-convs)."""
-        return tuple(l for l in range(1, self.L + 1)
-                     if not self.spec(l).shape_preserving)
+        """R — layers that cannot be pruned (shapes differ, non-convs, a
+        pre-activation net's barriers)."""
+        return tuple(l for l in range(1, self.L + 1) if not self.prunable(l))
 
     def layer_descs(self, params=None) -> list[LayerDesc]:
         descs = []
@@ -102,7 +134,7 @@ class ConvNet:
                 index=l, kind="dwconv" if s.depthwise else s.kind,
                 growth=(s.k - 1) if s.kind == "conv" else 0,
                 value=val,
-                prunable=s.shape_preserving,
+                prunable=self.prunable(l),
                 linearizable=(s.kind == "conv"),
                 meta={"stride": s.stride, "k": s.k},
             ))
@@ -119,7 +151,13 @@ class ConvNet:
         enumerator's stride-aware growth keeps the k coordinate exact, so
         the blow-up is a latency trade the DP prices from the table instead
         of a hard ban.
+
+        In a pre-activation net a multi-layer span lies inside one
+        residual block: its add reads the raw block input, which no conv
+        of the branch sees, so no block is Dirac-fused.
         """
+        if self.preact and j - i > 1 and not self._in_block(i, j):
+            return False
         if j - i > 1:
             for l in range(i + 1, j + 1):
                 if self.spec(l).kind != "conv":
@@ -173,6 +211,8 @@ class ConvNet:
 # ---------------------------------------------------------------------------
 
 def init_params(net: ConvNet, key: jax.Array, dtype=jnp.float32):
+    if net.preact:
+        return _init_preact(net, key, dtype)
     params = []
     keys = jax.random.split(key, net.L + len(net.skips) + 2)
     shapes = net.boundary_shapes()
@@ -199,11 +239,6 @@ def init_params(net: ConvNet, key: jax.Array, dtype=jnp.float32):
             elif s.norm == "gn":
                 p["gn"] = {"gamma": jnp.ones((s.cout,), dtype),
                            "beta": jnp.zeros((s.cout,), dtype)}
-        elif s.kind == "attn":
-            c = shapes[l - 1][2]
-            sub = jax.random.split(keys[l], 4)
-            p = {n: jax.random.normal(kk, (c, c), dtype) / math.sqrt(c)
-                 for n, kk in zip(("wq", "wk", "wv", "wo"), sub)}
         params.append(p)
     skip_params = []
     for idx, sk in enumerate(net.skips):
@@ -225,6 +260,61 @@ def init_params(net: ConvNet, key: jax.Array, dtype=jnp.float32):
                                       dtype) * math.sqrt(1.0 / c_final)
         head["b"] = jnp.zeros((net.num_classes,), dtype)
     return {"layers": params, "skips": skip_params, "head": head}
+
+
+def _init_preact(net: ConvNet, key: jax.Array, dtype):
+    """Seeded weights of a pre-activation net: variance scaling (fan-avg,
+    uniform) for every conv, 1×1 and dense layer, with no zero-initialised
+    output layer; small random biases; GroupNorm affines near (1, 0)."""
+    shapes = net.boundary_shapes()
+    keys = iter(jax.random.split(key, 8 * (net.L + len(net.skips)) + 8))
+
+    def vs(shape, fan_in, fan_out):
+        lim = math.sqrt(6.0 / (fan_in + fan_out))
+        return jax.random.uniform(next(keys), shape, dtype, -lim, lim)
+
+    def bias(c):
+        return 0.05 * jax.random.normal(next(keys), (c,), dtype)
+
+    def gn(c):
+        return {"gamma": 1.0 + 0.1 * jax.random.normal(next(keys), (c,),
+                                                       dtype),
+                "beta": 0.1 * jax.random.normal(next(keys), (c,), dtype)}
+
+    def dense(cin, cout):
+        return vs((cin, cout), cin, cout), bias(cout)
+
+    layers = []
+    for l in range(1, net.L + 1):
+        s = net.spec(l)
+        c = shapes[l - 1][2]
+        p = {}
+        if s.kind == "conv":
+            p["w"] = vs((s.k, s.k, c, s.cout), s.k * s.k * c,
+                        s.k * s.k * s.cout)
+            p["b"] = bias(s.cout)
+            if s.norm == "gn":
+                p["gn"] = gn(c)
+            if s.temb:
+                w, b = dense(net.temb_ch, s.cout)
+                p["temb"] = {"w": w, "b": b}
+        elif s.kind == "attn":
+            p["gn"] = gn(c)
+            for n in "qkvo":
+                p[f"w{n}"], p[f"b{n}"] = dense(c, c)
+        layers.append(p)
+    skips = []
+    for sk in net.skips:
+        # the block's own width: boundary ``end`` may also take a concat
+        cin, cout = shapes[sk.start][2], net.spec(sk.end).cout
+        skips.append({"w": vs((1, 1, cin, cout), cin, cout),
+                      "b": bias(cout)} if sk.proj else {})
+    out = {"layers": layers, "skips": skips, "head": {}}
+    if net.temb_ch:
+        w0, b0 = dense(net.temb_dim, net.temb_ch)
+        w1, b1 = dense(net.temb_ch, net.temb_ch)
+        out["temb"] = {"w0": w0, "b0": b0, "w1": w1, "b1": b1}
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -337,14 +427,83 @@ def _segment_gn(net: ConvNet, layers, seg: Segment):
     return None, 8
 
 
+def timestep_embedding(t, dim: int):
+    """Sinusoidal embedding of integer timesteps (DDPM's
+    ``get_timestep_embedding``): ``[sin(t f), cos(t f)]``, ``f_k =
+    10000^(-k / (dim/2 - 1))``."""
+    half = dim // 2
+    f = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                * -(math.log(10000.0) / (half - 1)))
+    a = t.astype(jnp.float32)[:, None] * f[None, :]
+    return jnp.concatenate([jnp.sin(a), jnp.cos(a)], axis=1)
+
+
+def time_features(p, t, dim: int):
+    """``SiLU(temb)`` of a time-conditioned net, ``(N, temb_ch)``: the
+    embedding through Dense → SiLU → Dense, then the SiLU every
+    per-image bias Dense reads."""
+    e = timestep_embedding(t, dim) @ p["w0"] + p["b0"]
+    return jax.nn.silu(jax.nn.silu(e) @ p["w1"] + p["b1"])
+
+
+def _condition(net: ConvNet, params, inputs):
+    """(images, {layer: per-image bias (N, C)}) of a net's inputs."""
+    if not net.temb_ch:
+        return inputs, {}
+    x, t = inputs
+    s = time_features(params["temb"], t, net.temb_dim)
+    return x, {l: s @ p["temb"]["w"] + p["temb"]["b"]
+               for l, p in enumerate(params["layers"], 1)
+               if net.spec(l).temb}
+
+
+def _pre_act(net: ConvNet, layers, l: int, x):
+    """The nonlinearity kept at boundary ``l - 1`` of a pre-activation
+    net: conv ``l``'s GroupNorm and activation of its input."""
+    s = net.spec(l)
+    if not net.preact or s.kind != "conv":
+        return x
+    if s.norm == "gn":
+        x = _gn(x, layers[l - 1]["gn"], s.gn_groups, s.gn_eps)
+    return _act(x, s.act)
+
+
+def upsample_nearest(x, factor: int):
+    """Nearest-neighbour ×``factor`` upsampling of an NHWC batch: each
+    pixel repeated into a ``factor × factor`` block, as a broadcast and a
+    reshape (``jax.image.resize``'s "nearest" computes the same values
+    through a gather, which the TPU compiler runs as a loop)."""
+    n, h, w, c = x.shape
+    x = jnp.broadcast_to(x[:, :, None, :, None, :],
+                         (n, h, factor, w, factor, c))
+    return x.reshape(n, h * factor, w * factor, c)
+
+
+def attn_block(x, p, groups: int, eps: float):
+    """DDPM's self-attention block: ``x + proj(softmax(q kᵀ/√C) v)`` on
+    ``GN(x)``, one head over the H·W positions, q/k/v/proj 1×1 with
+    biases."""
+    n, h, w, c = x.shape
+    t = _gn(x, p["gn"], groups, eps).reshape(n, h * w, c)
+    q = t @ p["wq"] + p["bq"]
+    k = t @ p["wk"] + p["bk"]
+    v = t @ p["wv"] + p["bv"]
+    a = jax.nn.softmax(jnp.einsum("nqc,nkc->nqk", q, k) * c ** -0.5,
+                       axis=-1)
+    o = jnp.einsum("nqk,nkc->nqc", a, v) @ p["wo"] + p["bo"]
+    return x + o.reshape(n, h, w, c)
+
+
 # ---------------------------------------------------------------------------
 # Replaced (pruned, unmerged) forward
 # ---------------------------------------------------------------------------
 
 def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None):
-    """Forward pass of the pruned-but-unmerged network under ``plan``."""
+    """Forward pass of the pruned-but-unmerged network under ``plan``;
+    ``x`` is an NHWC batch, or ``(x, t)`` for a time-conditioned net."""
     if plan is None:
         plan = identity_plan(net.L, net.layer_descs())
+    x, cond = _condition(net, params, x)
     layers = params["layers"]
     add_end = {sk.end: (sk.start, i) for i, sk in enumerate(net.skips)
                if sk.kind == "add"}
@@ -357,6 +516,7 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
         saved[0] = x
 
     for seg in plan.segments:
+        x = _pre_act(net, layers, seg.i + 1, x)
         Km, _ = segment_geometry(net, seg)
         lo = (Km - 1) // 2
         hi = Km - 1 - lo
@@ -364,7 +524,8 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
             x = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0)))
         local: dict[int, jax.Array] = {seg.i: x}   # halo'd in-segment values
         kept = set(seg.kept)
-        gn, gn_groups = _segment_gn(net, layers, seg)
+        gn, gn_groups = (None, 8) if net.preact else _segment_gn(net, layers,
+                                                                 seg)
         for l in seg.layers:
             s = net.spec(l)
             p = layers[l - 1]
@@ -372,16 +533,16 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
                 if l in kept:
                     w, b = _folded_wb(s, p)
                     x = _conv(x, w, s.stride, s.depthwise) + b
+                    if s.temb:
+                        x = x + cond[l][:, None, None, :]
             elif s.kind == "pool":
                 x = lax.reduce_window(
                     x, 0.0, lax.add, (1, s.k, s.k, 1),
                     (1, s.stride, s.stride, 1), "SAME") / (s.k * s.k)
             elif s.kind == "upsample":
-                n, h, w_, c = x.shape
-                x = jax.image.resize(x, (n, h * s.stride, w_ * s.stride, c),
-                                     "nearest")
+                x = upsample_nearest(x, s.stride)
             elif s.kind == "attn":
-                x = _tiny_self_attention(x, p)
+                x = attn_block(x, p, s.gn_groups, s.gn_eps)
             if l in add_end:
                 src, ski = add_end[l]
                 sk = net.skips[ski]
@@ -390,6 +551,8 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
                     # segment boundary (allowed_span guarantees it)
                     base = _apply_proj(saved[src], params["skips"][ski],
                                        _skip_stride(net, sk))
+                elif net.preact:
+                    base = saved[src]      # never Dirac-fused (allowed_span)
                 else:
                     base = local[src] if src >= seg.i else saved[src]
                 x = x + _center_crop_to(base, x)
@@ -398,8 +561,9 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
             local[l] = x
         if gn is not None:
             x = _gn(x, gn, gn_groups)
-        # boundary activation σ_j (σ_L is the identity, paper §2)
-        if seg.j < net.L:
+        # boundary activation σ_j (σ_L is the identity, paper §2); a
+        # pre-activation net applies it at the next segment's start
+        if seg.j < net.L and not net.preact:
             bspec = net.spec(seg.j)
             act = bspec.act
             if (net.act_after_merge and not seg.original
@@ -409,17 +573,6 @@ def apply_replaced(net: ConvNet, params, x, plan: CompressionPlan | None = None)
         if seg.j in need_save:
             saved[seg.j] = x
     return _apply_head(net, params, x)
-
-
-def _tiny_self_attention(x, p):
-    """Single-head self-attention over spatial positions (DDPM barrier)."""
-    n, h, w, c = x.shape
-    t = x.reshape(n, h * w, c)
-    q = t @ p["wq"]
-    k = t @ p["wk"]
-    v = t @ p["wv"]
-    a = jax.nn.softmax(q @ jnp.swapaxes(k, -1, -2) / math.sqrt(c), axis=-1)
-    return (t + (a @ v) @ p["wo"]).reshape(n, h, w, c)
 
 
 def _apply_head(net: ConvNet, params, x):
@@ -439,8 +592,8 @@ def _apply_head(net: ConvNet, params, x):
 def merge_segment(net: ConvNet, layers_params, seg: Segment):
     """Fold one segment into a single conv: returns (w, b, stride, dw)."""
     kept = set(seg.kept)
-    add_blocks = {sk.start: sk.end for sk in net.skips
-                  if sk.kind == "add" and not sk.proj}
+    add_blocks = {} if net.preact else {sk.start: sk.end for sk in net.skips
+                                        if sk.kind == "add" and not sk.proj}
 
     def compose(acc, w, b, stride, dw):
         if acc is None:
@@ -478,3 +631,26 @@ def merge_segment(net: ConvNet, layers_params, seg: Segment):
         return acc
 
     return chain(seg.i, seg.j)
+
+
+def segment_time_bias(net: ConvNet, layers_params, seg: Segment):
+    """The per-image time-bias Dense ``{w, b}`` at a segment's output, or
+    None: each kept ``temb`` conv's Dense, carried through the kept convs
+    after it by their tap sums (:func:`repro.core.merge.merge_bias_through`
+    — exact, since the segment pads once in front and every conv inside
+    it is VALID, so a bias stays spatially constant)."""
+    cond = None
+    kept = set(seg.kept)
+    for l in seg.layers:
+        s = net.spec(l)
+        if s.kind != "conv" or l not in kept:
+            continue
+        p = layers_params[l - 1]
+        if cond is not None:
+            w, _ = _folded_wb(s, p)
+            cond = {k: M.merge_bias_through(w, v, None, dw2=s.depthwise)
+                    for k, v in cond.items()}
+        if s.temb:
+            cond = dict(p["temb"]) if cond is None else {
+                k: v + p["temb"][k] for k, v in cond.items()}
+    return cond

@@ -141,7 +141,20 @@ class CNNHost:
         # grouped kernel), never alongside dense segments of equal shape.
         groups = cin if dw else 1
         return ("conv", h, w, cin, cout, K, S, bool(dw), groups, self.batch,
-                self.dtype_bytes, self.w_bytes, self.act_bytes)
+                self.dtype_bytes, self.w_bytes, self.act_bytes,
+                self._pre(seg), self._has_time_bias(seg))
+
+    def _pre(self, seg: Segment):
+        """(norm, groups, act) a pre-activation segment applies to its
+        input, or None."""
+        s = self.net.spec(seg.i + 1)
+        if not self.net.preact or s.kind != "conv" or (
+                s.norm is None and s.act == "none"):
+            return None
+        return (s.norm, s.gn_groups, s.act)
+
+    def _has_time_bias(self, seg: Segment) -> bool:
+        return any(self.net.spec(l).temb for l in seg.kept)
 
     def segment_probe(self, seg: Segment, params=None) -> ProbeCallable:
         """Jitted merged-segment forward as (fn, args) — AOT-lowerable."""
@@ -151,7 +164,10 @@ class CNNHost:
         s_last = self.net.spec(seg.j)
         if s_last.kind != "conv":
             if s_last.kind == "attn":
-                return ProbeCallable(jax.jit(cnn._tiny_self_attention),
+                attn = functools.partial(cnn.attn_block,
+                                         groups=s_last.gn_groups,
+                                         eps=s_last.gn_eps)
+                return ProbeCallable(jax.jit(attn),
                                      (x, params["layers"][seg.j - 1]))
             if s_last.kind == "pool":
                 @jax.jit
@@ -162,28 +178,40 @@ class CNNHost:
                         "SAME") / (s_last.k * s_last.k)
                 return ProbeCallable(pool_fn, (x,))
 
-            @jax.jit
-            def up_fn(x):
-                n, hh, ww, c = x.shape
-                return jax.image.resize(
-                    x, (n, hh * s_last.stride, ww * s_last.stride, c),
-                    "nearest")
+            up_fn = jax.jit(functools.partial(cnn.upsample_nearest,
+                                              factor=s_last.stride))
             return ProbeCallable(up_fn, (x,))
         wgt, b, stride, dw = cnn.merge_segment(self.net, params["layers"], seg)
         K = wgt.shape[0]
         lo, hi = (K - 1) // 2, (K - 1) - (K - 1) // 2
+        # a pre-activation segment's input GroupNorm and activation, and
+        # its per-image time bias, are part of what the unit costs
+        pre = self._pre(seg)
+        first = self.net.spec(seg.i + 1)
+        extra = {"gn": params["layers"][seg.i].get("gn") if pre else None,
+                 "cond": (jnp.zeros((self.batch, wgt.shape[-1]), jnp.float32)
+                          if self._has_time_bias(seg) else None)}
 
         @jax.jit
-        def fn(x, wgt, b):
+        def fn(x, wgt, b, extra):
+            if pre is not None:
+                if extra["gn"] is not None:
+                    x = cnn._gn(x, extra["gn"], first.gn_groups,
+                                first.gn_eps)
+                x = cnn._act(x, first.act)
             xp = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0))) if K > 1 else x
             # Time the segment exactly as it deploys at this batch:
             # through the Pallas fast path on TPU (strided and depthwise
             # segments included), oracle off-TPU.  A folded narrow input
             # builds its patch by batch (ops.fold_batch_major).
             if dw:
-                return kernels.depthwise_conv_op(xp, wgt, b, stride=stride)
-            return kernels.merged_conv_op(xp, wgt, b, stride=stride)
-        return ProbeCallable(fn, (x, wgt, b))
+                y = kernels.depthwise_conv_op(xp, wgt, b, stride=stride)
+            else:
+                y = kernels.merged_conv_op(xp, wgt, b, stride=stride)
+            if extra["cond"] is not None:
+                y = y + extra["cond"][:, None, None, :]
+            return y
+        return ProbeCallable(fn, (x, wgt, b, extra))
 
     def segment_callable(self, seg: Segment, params=None):
         """Zero-arg jitted merged-segment forward for wall-clock timing."""
@@ -278,6 +306,7 @@ class CNNHost:
         for seg in plan.segments:
             s_last = net.spec(seg.j)
             save_at = seg.j if seg.j in need_save else None
+            concat_from = cat_end.get(seg.j)
             if s_last.kind != "conv":
                 assert seg.j - seg.i == 1, "barriers are singleton segments"
                 if s_last.kind == "pool":
@@ -290,11 +319,19 @@ class CNNHost:
                         concat_from=cat_end.get(seg.j), save_at=save_at))
                 else:
                     units.append(ir.AttnUnit(
-                        save_at=save_at, params=dict(layers[seg.j - 1])))
+                        gn_groups=s_last.gn_groups, gn_eps=s_last.gn_eps,
+                        concat_from=concat_from, save_at=save_at,
+                        params=dict(layers[seg.j - 1])))
                 continue
             w, b, stride, dw = cnn.merge_segment(net, layers, seg)
-            gn, gn_groups = cnn._segment_gn(net, layers, seg)
-            act = s_last.act
+            if net.preact:
+                # σ_i is the first conv's input norm and activation; the
+                # boundary j keeps its raw value for the skips
+                gn, gn_groups = None, net.spec(seg.i + 1).gn_groups
+                act = "none"
+            else:
+                gn, gn_groups = cnn._segment_gn(net, layers, seg)
+                act = s_last.act
             if net.act_after_merge and not seg.original and act == "none":
                 act = "relu6"
             if seg.j >= net.L:
@@ -312,24 +349,36 @@ class CNNHost:
                 # Dirac-fused by merge_segment (proj blocks never fuse)
                 src, ski = add_end[seg.j]
                 sk = net.skips[ski]
-                if src < seg.i or sk.proj:
+                if src < seg.i or sk.proj or net.preact:
                     add_from = src
                     if sk.proj:
                         uparams["proj"] = dict(params["skips"][ski])
                         proj_stride = cnn._skip_stride(net, sk)
             if gn is not None:
                 uparams["gn"] = dict(gn)
+            pre = self._pre(seg)
+            if pre is not None and pre[0] == "gn":
+                uparams["pre_gn"] = dict(layers[seg.i]["gn"])
+            tb = cnn.segment_time_bias(net, layers, seg)
+            if tb is not None:
+                uparams["temb"] = tb
+            first = net.spec(seg.i + 1)
             units.append(ir.ConvUnit(
                 stride=stride, depthwise=dw, act=act, gn_groups=gn_groups,
+                gn_eps=first.gn_eps,
+                pre_act=pre[2] if pre is not None else "none",
                 proj_stride=proj_stride, add_from=add_from,
-                concat_from=cat_end.get(seg.j), save_at=save_at,
+                concat_from=concat_from, save_at=save_at,
                 quant=seg.quant, params=uparams))
         gparams = {}
+        meta = {"save_input": 0 in need_save, "head": net.head}
         if net.head == "classifier":
             gparams["head"] = dict(params["head"])
+        if net.temb_ch:
+            gparams["temb"] = dict(params["temb"])
+            meta["temb_dim"] = net.temb_dim
         return ir.annotate_axes(ir.UnitGraph(
-            family="cnn", units=tuple(units), params=gparams,
-            meta={"save_input": 0 in need_save, "head": net.head}))
+            family="cnn", units=tuple(units), params=gparams, meta=meta))
 
     def replaced_apply(self, plan: CompressionPlan, params=None):
         params = params or self.params

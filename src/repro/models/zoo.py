@@ -2,10 +2,10 @@
 
 ``resnet34`` / ``mobilenetv2`` / ``ddpm_unet`` mirror the paper's networks
 at full scale (used by the analytic latency tables and entry-count
-benchmarks); the ``tiny_*`` variants keep the same *structure* (skip kinds,
-strides, depthwise patterns, norms) at toy width/depth so that the measured
-pipeline — importance fine-tuning, wall-clock latency tables, DP, merging —
-runs on CPU in seconds.
+benchmarks); the ``tiny_*`` variants, and ``ddpm_unet`` at a small ``ch``,
+keep the same *structure* (skip kinds, strides, depthwise patterns, norms)
+at toy width/depth so that the measured pipeline — importance fine-tuning,
+wall-clock latency tables, DP, merging — runs on CPU in seconds.
 """
 from __future__ import annotations
 
@@ -103,42 +103,74 @@ def tiny_mobilenet(num_classes: int = 10, in_hw: int = 16, width: int = 8,
                    act_after_merge=True)
 
 
-def ddpm_unet(in_hw: int = 32, base: int = 128) -> ConvNet:
-    """DDPM-shaped UNet chain: down/up with skip-concat, GN, attn barrier."""
-    return _unet(in_hw, base, depth=2, blocks=2, norm="gn", attn=True)
+def ddpm_unet(in_hw: int = 32, ch: int = 128, ch_mult=(1, 2, 2, 2),
+              num_res_blocks: int = 2, attn_resolutions=(16,),
+              gn_groups: int = 32, gn_eps: float = 1e-6,
+              out_ch: int = 3) -> ConvNet:
+    """The DDPM CIFAR-10 UNet (Ho et al. 2020, arXiv 2006.11239, App. B;
+    ``diffusion_tf/models/unet.py``), defaults at its published sizes.
 
-
-def tiny_unet(in_hw: int = 16, base: int = 8, norm="gn", attn=True) -> ConvNet:
-    return _unet(in_hw, base, depth=1, blocks=2, norm=norm, attn=attn)
-
-
-def _unet(in_hw, base, depth, blocks, norm, attn) -> ConvNet:
+    A pre-activation chain (``ConvNet.preact``): ``conv_in``; per level
+    ``num_res_blocks`` ResBlocks (each followed by self-attention at the
+    ``attn_resolutions``) and a 3×3 stride-2 downsample; a middle
+    ResBlock–attention–ResBlock; per level up ``num_res_blocks + 1``
+    ResBlocks, each on the concat of the stream with the matching
+    down-path output, and a nearest ×2 upsample with a 3×3 conv; then
+    GN → SiLU → ``conv_out``.  A ResBlock is two specs,
+    ``conv1(SiLU(GN1(x))) + Dense(SiLU(temb))`` and ``conv2(SiLU(GN2(h)))``,
+    with a residual add (a 1×1 ``nin_shortcut`` where channels change).
+    The timestep embedding is ``ch`` wide, its MLP ``4·ch``.
+    """
     specs: list[ConvSpec] = []
     skips: list[SkipSpec] = []
-    enc_boundaries: list[tuple[int, int]] = []  # (boundary, channels)
-    specs.append(ConvSpec(4, base, 3, 1, act="silu", norm=norm))  # img + t chan
-    c = base
-    # encoder
-    for d in range(depth):
-        for _ in range(blocks):
-            specs.append(ConvSpec(c, c, 3, 1, act="silu", norm=norm))
-        enc_boundaries.append((len(specs), c))
-        specs.append(ConvSpec(c, 2 * c, 3, 2, act="silu", norm=norm))
-        c = 2 * c
-    # middle (+ attention barrier, as in DDPM at 16×16)
-    specs.append(ConvSpec(c, c, 3, 1, act="silu", norm=norm))
-    if attn:
-        specs.append(ConvSpec(c, c, 1, 1, kind="attn", act="none"))
-    specs.append(ConvSpec(c, c, 3, 1, act="silu", norm=norm))
-    # decoder
-    for d in reversed(range(depth)):
-        specs.append(ConvSpec(c, c, 2, 2, kind="upsample", act="none"))
-        src, src_c = enc_boundaries[d]
-        skips.append(SkipSpec("concat", src, len(specs)))
-        specs.append(ConvSpec(c + src_c, c // 2, 3, 1, act="silu", norm=norm))
-        c = c // 2
-        for _ in range(blocks - 1):
-            specs.append(ConvSpec(c, c, 3, 1, act="silu", norm=norm))
-    specs.append(ConvSpec(c, 3, 3, 1, act="none", norm=None))  # out conv
-    return ConvNet(tuple(specs), tuple(skips), in_hw=in_hw, in_ch=4,
-                   head="none")
+
+    def conv(cin, cout, stride=1, pre=False, temb=False):
+        specs.append(ConvSpec(cin, cout, 3, stride,
+                              act="silu" if pre else "none",
+                              norm="gn" if pre else None,
+                              gn_groups=gn_groups, gn_eps=gn_eps, temb=temb))
+
+    def res_block(cin, cout):
+        start = len(specs)
+        conv(cin, cout, pre=True, temb=True)
+        conv(cout, cout, pre=True)
+        skips.append(SkipSpec("add", start, start + 2, proj=cin != cout))
+
+    def attn(c):
+        specs.append(ConvSpec(c, c, 1, 1, act="none", norm="gn",
+                              gn_groups=gn_groups, gn_eps=gn_eps,
+                              kind="attn"))
+
+    conv(3, ch)                                   # conv_in
+    c, hw = ch, in_hw
+    hs = [(len(specs), c)]                        # down-path outputs
+    for level, m in enumerate(ch_mult):
+        for _ in range(num_res_blocks):
+            res_block(c, ch * m)
+            c = ch * m
+            if hw in attn_resolutions:
+                attn(c)
+            hs.append((len(specs), c))
+        if level != len(ch_mult) - 1:
+            conv(c, c, stride=2)                  # downsample
+            hw //= 2
+            hs.append((len(specs), c))
+    res_block(c, c)                               # middle
+    attn(c)
+    res_block(c, c)
+    for level, m in reversed(list(enumerate(ch_mult))):
+        for _ in range(num_res_blocks + 1):
+            src, c_src = hs.pop()
+            skips.append(SkipSpec("concat", src, len(specs)))
+            res_block(c + c_src, ch * m)
+            c = ch * m
+            if hw in attn_resolutions:
+                attn(c)
+        if level != 0:
+            specs.append(ConvSpec(c, c, 2, 2, kind="upsample", act="none"))
+            conv(c, c)                            # upsample conv
+            hw *= 2
+    specs.append(ConvSpec(c, out_ch, 3, 1, act="silu", norm="gn",
+                          gn_groups=gn_groups, gn_eps=gn_eps))  # conv_out
+    return ConvNet(tuple(specs), tuple(skips), in_hw=in_hw, in_ch=3,
+                   head="none", preact=True, temb_dim=ch, temb_ch=4 * ch)

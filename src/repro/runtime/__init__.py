@@ -27,6 +27,9 @@ plan is frozen:
   :func:`serve_continuous`): per-slot generation state vmapped through a
   jitted multi-slot chunk, mid-stream admission into vacated slots, and
   individual retirement on EOS / budget / deadline / NaN-abort.
+* :mod:`repro.runtime.sampler` — DDIM sampling of a time-conditioned
+  CNN graph (the DDPM UNet): :class:`Sampler` runs every denoising step
+  of a call inside one jitted program.
 
 **The logical-axis contract.**  Artifacts carry their sharding as data:
 every unit record (and the graph) ships an ``axes`` map {param keypath →
@@ -111,6 +114,7 @@ from .executor import (GraphExecutor, cache_shardings, execute,
 from .ir import (AttnUnit, ConvUnit, LowRankUnit, PoolUnit, SublayerUnit,
                  UnitGraph, UpsampleUnit, annotate_axes, bind_params,
                  graph_axes, graph_params)
+from .sampler import Sampler, ddim_schedule
 from .serving import (DISPOSITIONS, ContinuousEngine, ServeOutput,
                       ServeReport, WorkerLost, decode_tok_s,
                       generate_fused, greedy_token, pad_prompts,
@@ -125,7 +129,7 @@ __all__ = [
     "run_units", "slot_state",
     "AttnUnit", "ConvUnit", "LowRankUnit", "PoolUnit", "SublayerUnit",
     "UnitGraph", "UpsampleUnit", "annotate_axes", "bind_params",
-    "graph_axes", "graph_params",
+    "graph_axes", "graph_params", "Sampler", "ddim_schedule",
     "DISPOSITIONS", "ContinuousEngine", "ServeOutput", "ServeReport",
     "WorkerLost", "decode_tok_s", "generate_fused", "greedy_token",
     "pad_prompts", "ragged_prompts", "random_prompts", "serve_continuous",

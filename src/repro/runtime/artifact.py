@@ -11,7 +11,8 @@ run a compressed network without re-running the pipeline:
 * ``u<i>/<keypath>`` — the merged weights of unit ``i``, flattened by
   key-path exactly like :mod:`repro.checkpoint.ckpt`;
 * ``g/<keypath>`` — graph-level params (embed / final norm / unembed /
-  classifier head);
+  classifier head / a time-conditioned CNN's time MLP ``temb``, whose
+  embedding width rides in the graph meta as ``temb_dim``);
 * ``__fingerprint__`` — sha256 over the canonical spec JSON plus every
   array's key, dtype, shape, and raw bytes (the same content-hash style
   as :func:`repro.core.table_cache.pytree_digest`).
@@ -37,6 +38,13 @@ logical-axes annotations — no side-channel blobs, so the fingerprint,
 sharding, and crash contracts cover them unchanged.  v1/v2 artifacts
 (no ``quant`` field) still load: the unit dataclass default 'none' is
 exactly the fp semantics they were saved with.
+
+A time-conditioned CNN (the DDPM UNet) needs no new format: its units'
+input norms (``pre_gn``), time-bias Dense (``temb``) and attention
+weights are ordinary param arrays, and a conv unit's new static fields
+(``pre_act``, ``gn_eps``) default to what older artifacts meant.  An
+attention unit is now DDPM's block (GroupNorm, biased projections); an
+artifact holding the former bare attention unit does not run.
 """
 from __future__ import annotations
 

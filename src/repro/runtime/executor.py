@@ -40,12 +40,17 @@ scale padding or reshaping done per call), ``relayout`` (phase-major),
 ``kernel`` (the ``pallas_call``, named ``merged_conv`` or
 ``depthwise_conv``; off the TPU, the jnp oracle), ``crop`` (output
 slices) or ``epilogue`` (residual add, projection, concat, group norm,
-activation; a pool, upsample or attention unit whole); the classifier
-runs under ``head``.  The names
+activation, a pre-activation unit's input group norm and activation, the
+per-image time bias; a pool, upsample or attention unit whole); the
+classifier runs under ``head``.  A time-conditioned graph computes its
+time MLP and every unit's per-image bias ``(N, C)`` once a forward,
+under ``temb``, outside any unit.  The names
 reach the compiled HLO as ``op_name`` metadata and change nothing else.
 :meth:`GraphExecutor.apply` runs under the profiler span
 ``executor.apply``, and ``GraphExecutor.traces`` counts its traces
-(compiles) by input shape.
+(compiles) by input shape; the DDIM sampler (:mod:`repro.runtime.sampler`)
+runs under the same span, its update under ``sampler``, and counts its
+traces there too.
 """
 from __future__ import annotations
 
@@ -70,7 +75,8 @@ from . import ir
 
 
 def execute(graph: ir.UnitGraph, inputs, params=None):
-    """Run a UnitGraph: NHWC image batch (cnn) or token batch (transformer).
+    """Run a UnitGraph: NHWC image batch, or ``(images, timesteps)`` for a
+    time-conditioned one (cnn), or token batch (transformer).
 
     ``params`` optionally rebinds the graph's arrays (see
     :func:`repro.runtime.ir.graph_params`) — the pure-function form used
@@ -124,14 +130,31 @@ def _conv_kernel(u, x):
                      check_vma=False)(*args)
 
 
-def _execute_cnn(graph: ir.UnitGraph, x):
+def _time_biases(graph: ir.UnitGraph, t):
+    """Each unit's per-image time bias ``(N, C)``, or None, from the
+    integer timesteps ``t``: the time MLP once, then one Dense a unit."""
+    with jax.named_scope("temb"):
+        s = _cnn.time_features(graph.params["temb"], t,
+                               graph.meta["temb_dim"])
+        return [s @ u.params["temb"]["w"] + u.params["temb"]["b"]
+                if "temb" in u.params else None for u in graph.units]
+
+
+def _execute_cnn(graph: ir.UnitGraph, inputs):
+    """The CNN unit loop; ``inputs`` is an NHWC batch, or ``(x, t)`` for a
+    time-conditioned graph (``meta["temb_dim"]``)."""
+    if "temb_dim" in graph.meta:
+        x, t = inputs
+        cond = _time_biases(graph, t)
+    else:
+        x, cond = inputs, [None] * len(graph.units)
     saved: dict[int, jax.Array] = {}
     x = logical_constraint(x, _CNN_ACT)
     if graph.meta.get("save_input"):
         saved[0] = x
     for i, u in enumerate(graph.units):
         with jax.named_scope(f"unit{i:02d}"):
-            x = _cnn_unit(u, x, saved)
+            x = _cnn_unit(u, x, saved, cond[i])
         if u.save_at is not None:
             saved[u.save_at] = x
     if graph.meta.get("head") == "classifier":
@@ -142,10 +165,17 @@ def _execute_cnn(graph: ir.UnitGraph, x):
     return x
 
 
-def _cnn_unit(u, x, saved):
+def _cnn_unit(u, x, saved, cond=None):
     """One CNN unit, its ops under the role scopes of the module docstring:
-    ``pad`` here, the kernel wrappers' own roles, then ``epilogue``."""
+    a pre-activation unit's input norm under ``epilogue``, ``pad`` here,
+    the kernel wrappers' own roles, then ``epilogue``."""
     if u.kind == "conv":
+        if "pre_gn" in u.params or u.pre_act != "none":
+            with jax.named_scope("epilogue"):
+                if "pre_gn" in u.params:
+                    x = _cnn._gn(x, u.params["pre_gn"], u.gn_groups,
+                                 u.gn_eps)
+                x = _cnn._act(x, u.pre_act)
         K = u.params["w"].shape[0]
         lo = (K - 1) // 2
         hi = K - 1 - lo
@@ -155,6 +185,8 @@ def _cnn_unit(u, x, saved):
         x = _conv_kernel(u, x)
     with jax.named_scope("epilogue"):
         if u.kind == "conv":
+            if cond is not None:
+                x = x + cond[:, None, None, :]
             if u.add_from is not None:
                 base = saved[u.add_from]
                 if "proj" in u.params:
@@ -174,13 +206,13 @@ def _cnn_unit(u, x, saved):
             if u.concat_from is not None:
                 x = jnp.concatenate([x, saved[u.concat_from]], axis=-1)
         elif u.kind == "upsample":
-            n, h, w_, c = x.shape
-            x = jax.image.resize(
-                x, (n, h * u.factor, w_ * u.factor, c), "nearest")
+            x = _cnn.upsample_nearest(x, u.factor)
             if u.concat_from is not None:
                 x = jnp.concatenate([x, saved[u.concat_from]], axis=-1)
         elif u.kind == "attn":
-            x = _cnn._tiny_self_attention(x, u.params)
+            x = _cnn.attn_block(x, u.params, u.gn_groups, u.gn_eps)
+            if u.concat_from is not None:
+                x = jnp.concatenate([x, saved[u.concat_from]], axis=-1)
         else:
             raise ValueError(f"unit kind {u.kind!r} in cnn graph")
         return logical_constraint(x, _CNN_ACT)

@@ -29,8 +29,11 @@ Design rules:
   empty ``axes`` simply means fully replicated.
 
 CNN unit semantics (epilogue order matches the merged forward that the
-merge-equality tests certify): conv → skip-add → concat → group-norm →
-boundary activation → save.
+merge-equality tests certify): [input group-norm → activation] → conv →
+per-image time bias → skip-add → concat → group-norm → boundary
+activation → save.  The bracketed input nonlinearity is a
+pre-activation net's (DDPM): it feeds the conv only, while skips read
+the raw saved boundary values.
 """
 from __future__ import annotations
 
@@ -44,8 +47,13 @@ class ConvUnit:
 
     ``params``: ``w`` (Kh,Kw,Cin|1,Cout), ``b`` (Cout,), optional
     ``gn`` {gamma, beta} (group-norm moved to the segment end, paper
-    Appendix A) and optional ``proj`` {w, b} (1×1 projection shortcut of
-    a skip-add ending at this unit's boundary).
+    Appendix A), optional ``proj`` {w, b} (1×1 projection shortcut of
+    a skip-add ending at this unit's boundary), optional ``pre_gn``
+    {gamma, beta} (the input group-norm, with ``gn_groups``/``gn_eps``,
+    followed by ``pre_act``, of a pre-activation segment) and optional
+    ``temb`` {w (D,Cout), b (Cout,)}: the Dense from the graph's time
+    features to the unit's per-image bias, already folded through the
+    segment's convs.
 
     ``quant`` != 'none' marks a low-precision unit (artifact format v3):
     ``w`` is stored narrow (int8 / fp8) and ``params`` carries the
@@ -59,6 +67,8 @@ class ConvUnit:
     depthwise: bool = False
     act: str = "none"               # boundary activation σ_j ('none' at σ_L)
     gn_groups: int = 8
+    gn_eps: float = 1e-5
+    pre_act: str = "none"           # activation after ``pre_gn``
     proj_stride: int = 1
     add_from: int | None = None     # skip-add source boundary id
     concat_from: int | None = None  # U-Net concat source boundary id
@@ -95,13 +105,18 @@ class UpsampleUnit:
 
 @dataclasses.dataclass
 class AttnUnit:
-    """Single-head spatial self-attention barrier (DDPM middle block).
+    """DDPM's self-attention block as a barrier unit: ``x + proj(softmax(
+    q kᵀ/√C) v)`` on the group-normed input, one head over H·W positions.
 
-    ``params``: ``wq``, ``wk``, ``wv``, ``wo`` — passed through unmerged
-    (attention is never linearizable).
+    ``params``: ``gn`` {gamma, beta}, ``wq``/``bq``, ``wk``/``bk``,
+    ``wv``/``bv``, ``wo``/``bo`` — passed through unmerged (attention is
+    never linearizable).
     """
 
     kind = "attn"
+    gn_groups: int = 32
+    gn_eps: float = 1e-6
+    concat_from: int | None = None
     save_at: int | None = None
     axes: dict = dataclasses.field(default_factory=dict)
     params: dict = dataclasses.field(default_factory=dict)
@@ -160,13 +175,16 @@ class UnitGraph:
     ``family``: 'cnn' | 'transformer' — selects the executor loop.
 
     ``params`` (graph-level, outside any unit):
-      cnn          — optional ``head`` {w, b} (classifier);
+      cnn          — optional ``head`` {w, b} (classifier), optional
+                     ``temb`` {w0, b0, w1, b1} (time MLP);
       transformer  — optional ``embed``, ``final_norm``, optional
                      ``unembed``.
 
     ``meta`` (static):
       cnn          — ``save_input`` (bool: boundary 0 feeds a skip),
-                     ``head`` ('classifier' | 'none');
+                     ``head`` ('classifier' | 'none'), ``temb_dim``
+                     (sinusoidal width; present iff time-conditioned:
+                     the graph then takes ``(x, t)``);
       transformer  — ``config`` (the :class:`ArchConfig`; serialized as
                      a plain dict in the artifact spec).
     """

@@ -124,9 +124,16 @@ def test_rank_merge_chain(n, seed):
 NETS = {
     "tiny_resnet": lambda: zoo.tiny_resnet(),
     "tiny_resnet_bn": lambda: zoo.tiny_resnet(norm="bn"),
+    "tiny_resnet_gn": lambda: zoo.tiny_resnet(width=16, norm="gn"),
     "tiny_mobilenet": lambda: zoo.tiny_mobilenet(),
-    "tiny_unet": lambda: zoo.tiny_unet(),
-    "tiny_unet_plain": lambda: zoo.tiny_unet(norm=None, attn=False),
+    "tiny_unet": lambda: zoo.ddpm_unet(in_hw=16, ch=8, ch_mult=(1, 2),
+                                       num_res_blocks=1,
+                                       attn_resolutions=(8,), gn_groups=4),
+    "tiny_unet_plain": lambda: zoo.ddpm_unet(in_hw=16, ch=8,
+                                             ch_mult=(1, 2),
+                                             num_res_blocks=1,
+                                             attn_resolutions=(),
+                                             gn_groups=4),
 }
 
 
@@ -137,6 +144,8 @@ def test_replaced_equals_merged(name, method):
     params = cnn.init_params(net, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (2, net.in_hw, net.in_hw, net.in_ch))
+    if net.temb_ch:                  # a time-conditioned net takes (x, t)
+        x = (x, jnp.array([10, 900]))
     host = cnn_host.CNNHost(net, params, batch=2)
     tested = 0
     for ratio in (0.55, 0.75, 0.95):

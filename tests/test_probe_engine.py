@@ -3,7 +3,7 @@ bit-identical to the sequential reference under the analytic oracle
 (entries, Pareto drops, DP plans), within tolerance under the wall-clock
 oracle, and the vmapped Dirac-masked importance batch must reproduce the
 scalar Eq. 4 fine-tune exactly.  Plus: cache round-trips, mixed
-conv/attn/pool barrier hosts, and the pmap-sharded fine-tune path."""
+conv/attn/upsample barrier hosts, and the pmap-sharded fine-tune path."""
 import textwrap
 
 import jax
@@ -33,8 +33,11 @@ def resnet_host():
 
 @pytest.fixture(scope="module")
 def unet_host():
-    """Mixed-barrier chain: convs + attn + pool + upsample + GN."""
-    return _host(zoo.tiny_unet(in_hw=8, base=4, norm="gn", attn=True))
+    """Mixed-barrier chain: the DDPM UNet's pre-activation ResBlocks,
+    attention, upsample and concat at toy width."""
+    return _host(zoo.ddpm_unet(in_hw=8, ch=8, ch_mult=(1, 2),
+                               num_res_blocks=1, attn_resolutions=(4,),
+                               gn_groups=4))
 
 
 def _spec(net, steps=2):

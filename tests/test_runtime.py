@@ -37,10 +37,14 @@ _SUBPROC_ENV = subprocess_env()
 CNN_ZOO = {
     "tiny_resnet": lambda: zoo.tiny_resnet(num_classes=4, in_hw=8, width=4,
                                            blocks=(2,)),
+    "tiny_resnet_gn": lambda: zoo.tiny_resnet(num_classes=4, in_hw=8,
+                                              width=16, blocks=(2,),
+                                              norm="gn"),
     "tiny_mobilenet": lambda: zoo.tiny_mobilenet(num_classes=4, in_hw=8,
                                                  width=8),
-    "tiny_unet": lambda: zoo.tiny_unet(in_hw=8, base=4, norm="gn",
-                                       attn=True),
+    "tiny_unet": lambda: zoo.ddpm_unet(in_hw=8, ch=8, ch_mult=(1, 2),
+                                       num_res_blocks=1,
+                                       attn_resolutions=(4,), gn_groups=4),
 }
 
 TRANSFORMER_ARCHS = ("smollm-135m", "granite-moe-1b-a400m",
@@ -53,6 +57,8 @@ def _cnn_setup(name):
     host = cnn_host.CNNHost(net, params, batch=2)
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (2, net.in_hw, net.in_hw, net.in_ch))
+    if net.temb_ch:                  # a time-conditioned net takes (x, t)
+        x = (x, jnp.array([3, 700]))
     return net, params, host, x
 
 

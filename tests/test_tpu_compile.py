@@ -18,12 +18,14 @@ test compiles a whole merged network under a four-chip
 mesh: XLA cannot partition a Mosaic kernel, so the executor must run each
 one per data shard.  The tiny plans of the benchmark's tests are compiled
 for one chip to see that the kernels' names and the executor's unit and
-role scopes reach the compiled text.
+role scopes reach the compiled text, and the DDPM sampler's tiny plan is
+compiled as the one program a sampling call is.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each test worker imports
 every test file.
 """
+import os
 import re
 
 import jax
@@ -35,7 +37,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
 from repro.kernels import ops
-from _tiny_plans import PLANS, tiny_graph
+from _tiny_plans import DATA, PLANS, tiny_graph
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -250,3 +252,41 @@ def test_cnn_executor_names_reach_the_compiled_forward(one_chip, zoo_name,
         elif op and "jit(" in op.group(1):
             assert ROLE_SCOPE.search(op.group(1)), (m.group(1), op.group(1))
     assert kernels == sum(u.kind == "conv" for u in graph.units)
+
+
+def test_ddpm_sampler_compiles_as_one_program(one_chip):
+    """The DDPM sampler under the tiny benchmark plan (merged and pruned
+    ResBlocks) compiles for the chip as one program: each conv unit's
+    kernel once in the loop body, named after its kind in its unit's
+    ``kernel`` scope, the time MLP under ``temb`` and the DDIM update
+    under ``sampler``."""
+    from repro import runtime
+    from repro.core.plan import CompressionPlan
+    from repro.models import cnn, cnn_host, zoo
+    from repro.runtime import ir
+
+    net = zoo.ddpm_unet(in_hw=16, ch=16, ch_mult=(1, 2), num_res_blocks=1,
+                        attn_resolutions=(8,), gn_groups=8)
+    host = cnn_host.CNNHost(net, cnn.init_params(net, jax.random.PRNGKey(0)))
+    with open(os.path.join(DATA, "tiny_ddpm.plan.json")) as f:
+        graph = host.lower_plan(CompressionPlan.from_json(f.read()))
+    ex = runtime.GraphExecutor(graph)
+    smp = runtime.Sampler(ex, 2, timesteps=1000, beta_start=1e-4,
+                          beta_end=0.02)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        ir.graph_params(graph))
+    x = jax.ShapeDtypeStruct((2, 16, 16, 3), jnp.float32, sharding=one_chip)
+    with kernels.ops.force_backend("pallas"), \
+            jax.default_matmul_precision("highest"):
+        hlo = smp._sample.lower(shapes, x).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if KERNEL in ln]
+    assert len(calls) == sum(u.kind == "conv" for u in graph.units)
+    for ln in calls:
+        op = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert re.search(r"/unit\d\d/kernel/(merged_conv|depthwise_conv)/"
+                         r"pallas_call$", op), op
+    assert "/temb/" in hlo and "/sampler/" in hlo
+    # the step loop is the program's only loop: nearest upsampling is a
+    # broadcast, not a gather the compiler would run as loops of its own
+    assert len(re.findall(r"= \(.*\) while\(", hlo)) == 1
